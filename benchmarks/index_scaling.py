@@ -1,7 +1,8 @@
 """Streaming index lifecycle costs: insert throughput, query latency as a
 function of sealed-segment count, the cost + payoff of compaction, and the
 device-scaling axis of the sharded planner (replicated vs list-sharded
-layout on 1/2/4 simulated devices)."""
+layout on 1/2/4 devices: simulated CPU devices in subprocesses, or the
+real devices in this process on an accelerator)."""
 
 from __future__ import annotations
 
@@ -20,49 +21,72 @@ from repro.index import IndexConfig, StreamingIndex
 from . import common
 from .common import Bench, timeit
 
-# Runs in a subprocess per device count: XLA fixes the host device count at
-# first init, so each mesh size needs a fresh process.  Prints one JSON
-# marker line the parent collects into the shared Bench.
-_DEVICE_LEG = r"""
-import json, numpy as np, jax
-from repro.core.pq import PQConfig
-from repro.data.timeseries import random_walks
-from repro.index import IndexConfig, StreamingIndex, search_sharded
-from benchmarks import common
-from benchmarks.common import timeit
+def _device_leg(n_dev: int, D: int, n_lists: int, cap: int,
+                n_seg: int) -> dict:
+    """One device-scaling leg: the same index searched directly and by
+    both sharded plans on a mesh of the first ``n_dev`` devices."""
+    import numpy as np
+    from repro.index import search_sharded
+    from repro.launch.mesh import make_search_mesh
 
-n_dev = int({n_dev})
-assert len(jax.devices()) == n_dev
-D, n_lists, cap, n_seg = {D}, {n_lists}, {cap}, {n_seg}
-cfg = IndexConfig(
-    pq=PQConfig(n_sub=4, codebook_size=32, use_prealign=False,
-                **common.measure_config_fields(),
-                kmeans_iters=3, dba_iters=1),
-    n_lists=n_lists, hot_capacity=cap, coarse_iters=4, n_shards=n_dev)
-index = StreamingIndex.bootstrap(
-    jax.random.PRNGKey(0), random_walks(2 * cap, D, seed=0), cfg)
-index.insert(random_walks(n_seg * cap, D, seed=2))
-index.compact()                       # one merged, placement-balanced shard
-Q = random_walks(16, D, seed=99)
-lat, lat_p99 = dict(), dict()
-t = timeit(lambda: index.search(Q, n_probe=4, topk=3), repeats=3)
-lat["direct"], lat_p99["direct"] = t["median_s"], t["p99_s"]
-for part in ("queries", "lists"):
-    t = timeit(lambda: search_sharded(index, Q, n_probe=4, topk=3,
-                                      partition=part), repeats=3)
-    lat[part], lat_p99[part] = t["median_s"], t["p99_s"]
-sg = index.segments[0]
-mc = index.memory_cost()
-print("LEG:" + json.dumps(dict(
-    n_devices=n_dev, latency_s=lat, latency_p99_s=lat_p99,
-    live_rows=index.n_live(),
-    shard_cap=sg.shard_cap, max_list=int(np.asarray(sg.list_len).max()),
-    code_bytes=mc["code_bytes"],
-    max_device_bytes=mc.get("max_device_bytes", mc["total_bytes"]),
-    replicated_bytes=mc.get("replicated_bytes", 0),
-    partitioned_bytes=mc.get("partitioned_bytes",
-                             mc["code_bytes"] + mc["sidecar_bytes"]))))
+    mesh = make_search_mesh(n_dev)
+    cfg = IndexConfig(
+        pq=PQConfig(n_sub=4, codebook_size=32, use_prealign=False,
+                    **common.measure_config_fields(),
+                    kmeans_iters=3, dba_iters=1),
+        n_lists=n_lists, hot_capacity=cap, coarse_iters=4, n_shards=n_dev)
+    index = StreamingIndex.bootstrap(
+        jax.random.PRNGKey(0), random_walks(2 * cap, D, seed=0), cfg)
+    index.insert(random_walks(n_seg * cap, D, seed=2))
+    index.compact()                   # one merged, placement-balanced shard
+    Q = random_walks(16, D, seed=99)
+    lat, lat_p99 = dict(), dict()
+    t = timeit(lambda: index.search(Q, n_probe=4, topk=3), repeats=3)
+    lat["direct"], lat_p99["direct"] = t["median_s"], t["p99_s"]
+    for part in ("queries", "lists"):
+        t = timeit(lambda: search_sharded(index, Q, n_probe=4, topk=3,
+                                          mesh=mesh, partition=part),
+                   repeats=3)
+        lat[part], lat_p99[part] = t["median_s"], t["p99_s"]
+    sg = index.segments[0]
+    mc = index.memory_cost()
+    return dict(
+        n_devices=n_dev, latency_s=lat, latency_p99_s=lat_p99,
+        live_rows=index.n_live(),
+        shard_cap=sg.shard_cap, max_list=int(np.asarray(sg.list_len).max()),
+        code_bytes=mc["code_bytes"],
+        max_device_bytes=mc.get("max_device_bytes", mc["total_bytes"]),
+        replicated_bytes=mc.get("replicated_bytes", 0),
+        partitioned_bytes=mc.get("partitioned_bytes",
+                                 mc["code_bytes"] + mc["sidecar_bytes"]))
+
+
+# On the CPU each leg runs in a subprocess: XLA fixes the host device count
+# at first init, so each simulated mesh size needs a fresh process.  It
+# prints one JSON marker line the parent collects into the shared Bench.
+_CPU_LEG = r"""
+import json
+from benchmarks import common
+from benchmarks.index_scaling import _device_leg
+common.set_measure({measure!r})
+print("LEG:" + json.dumps(_device_leg({n_dev}, {D}, {n_lists}, {cap},
+                                      {n_seg})))
 """
+
+
+def _cpu_leg(n_dev: int, D: int, n_lists: int, cap: int,
+             n_seg: int) -> dict:
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS=f"--xla_force_host_platform_device_count={n_dev}")
+    code = _CPU_LEG.format(measure=common.MEASURE, n_dev=n_dev, D=D,
+                           n_lists=n_lists, cap=cap, n_seg=n_seg)
+    res = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True, timeout=1200)
+    if res.returncode != 0:
+        raise RuntimeError(
+            f"device leg n_dev={n_dev} failed:\n{res.stderr[-2000:]}")
+    return json.loads(next(ln for ln in res.stdout.splitlines()
+                           if ln.startswith("LEG:"))[4:])
 
 
 def _make_index(D: int, n_lists: int, hot_capacity: int,
@@ -113,25 +137,23 @@ def run(quick: bool = True) -> Bench:
           post_compact_latency_p99_s=t["p99_s"])
 
     # --- device scaling: replicated vs list-sharded layout ------------------
-    # Simulated host devices share one CPU, so wall-clock speedup is not the
-    # point here; what the rows pin down is the *structure* of the scale-out:
-    # per-device occupancy (hence sealed-code HBM) shrinking ~linearly with
-    # the mesh, and the cost of the all_gather fan-in merge relative to the
-    # query-sharded plan doing identical kernel work.
+    # On the CPU the devices are simulated and share one CPU, so wall-clock
+    # speedup is not the point there; what the rows pin down is the
+    # *structure* of the scale-out: per-device occupancy (hence sealed-code
+    # HBM) shrinking ~linearly with the mesh, and the cost of the
+    # all_gather fan-in merge relative to the query-sharded plan doing
+    # identical kernel work.  On an accelerator the legs run in this
+    # process over the real devices (a child process could not reach a
+    # chip this process holds).
     n_seg_dev = 4
+    on_chip = jax.default_backend() != "cpu"
     for n_dev in (1, 2, 4):
-        env = dict(os.environ, JAX_PLATFORMS="cpu",
-                   XLA_FLAGS=f"--xla_force_host_platform_device_count="
-                             f"{n_dev}")
-        code = _DEVICE_LEG.format(n_dev=n_dev, D=D, n_lists=n_lists,
-                                  cap=cap, n_seg=n_seg_dev)
-        res = subprocess.run([sys.executable, "-c", code], env=env,
-                             capture_output=True, text=True, timeout=1200)
-        if res.returncode != 0:
-            raise RuntimeError(
-                f"device leg n_dev={n_dev} failed:\n{res.stderr[-2000:]}")
-        leg = json.loads(next(ln for ln in res.stdout.splitlines()
-                              if ln.startswith("LEG:"))[4:])
+        if on_chip:
+            if n_dev > len(jax.devices()):
+                continue
+            leg = _device_leg(n_dev, D, n_lists, cap, n_seg_dev)
+        else:
+            leg = _cpu_leg(n_dev, D, n_lists, cap, n_seg_dev)
         lat = leg["latency_s"]
         # the placement guarantee, on the physically sealed layout:
         # per-device rows <= perfect split + one list's worth

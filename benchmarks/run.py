@@ -46,6 +46,8 @@ def main() -> None:
                          "committed BENCH_* summaries (CPU/interpret "
                          "baselines) are never touched")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.smoke and args.full:
         ap.error("--smoke and --full are mutually exclusive")
     if args.smoke:

@@ -44,7 +44,7 @@ TRACE_WRAPPERS = frozenset({
     "jax.checkpoint", "jax.remat", "jax.lax.scan", "jax.lax.map",
     "jax.lax.while_loop", "jax.lax.cond", "jax.lax.fori_loop",
     "jax.lax.switch", "jax.lax.associative_scan",
-    "jax.experimental.shard_map.shard_map",
+    "jax.shard_map",
 })
 
 # the Pallas launch entry point (``pl.pallas_call`` under the canonical

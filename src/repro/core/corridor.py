@@ -203,7 +203,8 @@ def corridor_sweep(A: jnp.ndarray, B: jnp.ndarray, lo: jnp.ndarray,
     from ..kernels.dtw_band.kernel import wavefront_compressed
     L = A.shape[1]
     return wavefront_compressed(
-        A.astype(jnp.float32), B.astype(jnp.float32), length=L,
+        A.astype(jnp.float32), jnp.flip(B.astype(jnp.float32), axis=1),
+        length=L,
         window=_eff_window(L, window), width=width, measure=measure,
         corridor=(lo, hi))
 

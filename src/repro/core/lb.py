@@ -87,8 +87,12 @@ def lb_keogh(q: jnp.ndarray, upper: jnp.ndarray, lower: jnp.ndarray) -> jnp.ndar
 
 def lb_kim(q: jnp.ndarray, c: jnp.ndarray) -> jnp.ndarray:
     """Simplified LB_Kim: first and last points are always aligned by DTW,
-    so their squared differences lower-bound the squared DTW cost."""
-    return (q[..., 0] - c[..., 0]) ** 2 + (q[..., -1] - c[..., -1]) ** 2
+    so their squared differences lower-bound the squared DTW cost.
+
+    The end points are read as static slices: an integer index lowers to a
+    dynamic slice, which a Pallas TPU kernel cannot hold."""
+    kim = (q[..., :1] - c[..., :1]) ** 2 + (q[..., -1:] - c[..., -1:]) ** 2
+    return kim[..., 0]
 
 
 def lb_cascade(q: jnp.ndarray, centroids: jnp.ndarray,

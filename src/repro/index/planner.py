@@ -40,7 +40,6 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from .. import obs
@@ -82,14 +81,14 @@ def _search_query_sharded(index: StreamingIndex, Q: jnp.ndarray,
                            n_probe=n_probe, topk=topk, dim=index.dim,
                            two_level=two_level, q_valid=qv)
 
-    # check_rep=False: jax has no replication rule for pallas_call, and the
+    # check_vma=False: jax has no varying-axes rule for pallas_call, and the
     # out_specs fully describe the (embarrassingly parallel) output layout.
     with obs.span("sharded.execute") as sp:
-        d, ids = sp.fence(shard_map(
+        d, ids = sp.fence(jax.shard_map(
             per_device, mesh=mesh,
             in_specs=(P(), P("search", None), P("search")),
             out_specs=(P("search", None), P("search", None)),
-            check_rep=False)(plan, Q, q_valid))
+            check_vma=False)(plan, Q, q_valid))
     return d[:Nq], ids[:Nq]
 
 
@@ -194,11 +193,11 @@ def _search_list_sharded(index: StreamingIndex, Q: jnp.ndarray,
     view_spec = (P("search", None, None), P("search", None),
                  P("search", None), P("search", None), P("search", None))
     with obs.span("sharded.execute") as sp:
-        d, ids = sp.fence(shard_map(
+        d, ids = sp.fence(jax.shard_map(
             per_device, mesh=mesh,
             in_specs=(P(), P(), P(), P(), tuple(view_spec for _ in views)),
             out_specs=(P(None, None), P(None, None)),
-            check_rep=False)(dc, qluts, Q, hot, views))
+            check_vma=False)(dc, qluts, Q, hot, views))
     return d, ids
 
 

@@ -31,8 +31,13 @@ Two generations of the same anti-diagonal sweep live here:
     All shifts are lane rotates selected by the (scalar) drift — no gathers.
 
 TPU notes (both kernels):
-  * the diagonal gather ``b[d - i]`` is a dynamic slice of a pre-reversed,
-    pre-padded copy of ``b`` (built once per tile) — no scatter/gather ops;
+  * every kernel takes the second operand already *reversed* along time
+    (``b_rev = b[:, ::-1]``, one XLA op in the ops layer) — Mosaic has no
+    in-kernel ``rev``;
+  * the diagonal gather ``b[d - i]`` is then a window of a lane-padded copy
+    of ``b_rev``, read as a lane rotate by the scalar diagonal offset
+    (``pltpu.roll``) plus a static prefix slice — no dynamic slices, no
+    scatter/gather ops;
   * the band geometry is integer arithmetic on the loop counter, so shapes
     never depend on data.
 
@@ -52,14 +57,17 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ...core import measures
 from ...core.dispatch import effective_window
 from ...core.measures import MeasureArg
+from ..common import CompiledRouteUnsupported, carry_full, cdiv
 
 __all__ = [
     "dtw_band_kernel",
     "dtw_band_compressed_kernel",
+    "dtw_band_cdist_kernel",
     "dtw_band_adaptive_kernel",
     "make_dtw_band_call",
     "make_dtw_band_cdist_call",
@@ -68,6 +76,30 @@ __all__ = [
 ]
 
 _NEG_SAFE_INF = 3.0e38  # finite stand-in for +inf (avoids inf-inf NaNs)
+_LANES = 128            # TPU vreg lane count: rotates run on whole lane tiles
+
+
+def _pad_lanes(x: jnp.ndarray, left: int = 0,
+               min_width: int = 0) -> jnp.ndarray:
+    """Zero-pad ``x (rows, n)`` with ``left`` lanes in front and enough
+    behind to reach a multiple of 128 lanes that is at least
+    ``min_width``."""
+    rows, n = x.shape
+    total = cdiv(max(left + n, min_width), _LANES) * _LANES
+    parts = [jnp.zeros((rows, left), x.dtype)] if left else []
+    parts.append(x)
+    if total > left + n:
+        parts.append(jnp.zeros((rows, total - left - n), x.dtype))
+    return jnp.concatenate(parts, axis=1)
+
+
+def _window(padded: jnp.ndarray, base, width: int) -> jnp.ndarray:
+    """``padded[:, base:base + width]`` for a scalar ``base`` (the caller
+    guarantees ``base + width <= padded.shape[1]``): a lane rotate that
+    brings ``base`` to lane 0, then a static prefix slice — what Mosaic
+    lowers in place of a dynamic slice."""
+    total = padded.shape[1]
+    return pltpu.roll(padded, (total - base) % total, axis=1)[:, :width]
 
 
 def band_width(length: int, window: Optional[int], lane: int = 8) -> int:
@@ -89,20 +121,18 @@ def band_width(length: int, window: Optional[int], lane: int = 8) -> int:
 # Full-width kernel (legacy / benchmark baseline)
 # ---------------------------------------------------------------------------
 
-def dtw_band_kernel(a_ref, b_ref, o_ref, *, length: int, window: int,
+def dtw_band_kernel(a_ref, b_rev_ref, o_ref, *, length: int, window: int,
                     block: int):
-    """Kernel body: ``a_ref (block, L)``, ``b_ref (block, L)`` ->
-    ``o_ref (block, 1)`` squared banded DTW costs."""
+    """Kernel body: ``a_ref (block, L)``, ``b_rev_ref (block, L)`` (time
+    reversed) -> ``o_ref (block, 1)`` squared banded DTW costs."""
     L = length
     a = a_ref[...].astype(jnp.float32)
-    b = b_ref[...].astype(jnp.float32)
+    b_rev = b_rev_ref[...].astype(jnp.float32)
 
     idx = jax.lax.broadcasted_iota(jnp.int32, (block, L), 1)
     # b_big[:, L + t] == b_rev[:, t]; diagonal d needs v[i] = b[d - i]
     #   = b_rev[i + L - 1 - d] = b_big[:, i + 2L - 1 - d].
-    b_rev = jnp.flip(b, axis=1)
-    zeros = jnp.zeros((block, L), jnp.float32)
-    b_big = jnp.concatenate([zeros, b_rev, zeros], axis=1)
+    b_big = _pad_lanes(b_rev, left=L, min_width=3 * L)
 
     inf = jnp.float32(_NEG_SAFE_INF)
 
@@ -110,7 +140,7 @@ def dtw_band_kernel(a_ref, b_ref, o_ref, *, length: int, window: int,
         prev1, prev2 = carry
         j = d - idx
         valid = (j >= 0) & (j < L) & (jnp.abs(idx - j) <= window)
-        v = jax.lax.dynamic_slice_in_dim(b_big, 2 * L - 1 - d, L, axis=1)
+        v = _window(b_big, 2 * L - 1 - d, L)
         cost = (a - v) ** 2
 
         shift1 = jnp.where(idx == 0, inf, jnp.roll(prev1, 1, axis=1))
@@ -122,8 +152,8 @@ def dtw_band_kernel(a_ref, b_ref, o_ref, *, length: int, window: int,
         diag = jnp.minimum(diag, inf)
         return diag, prev1
 
-    init = (jnp.full((block, L), inf), jnp.full((block, L), inf))
-    last, _ = jax.lax.fori_loop(0, 2 * L - 1, step, init)
+    init = carry_full((block, L), _NEG_SAFE_INF)
+    last, _ = jax.lax.fori_loop(0, 2 * L - 1, step, (init, init))
     o_ref[...] = last[:, L - 1:L]
 
 
@@ -131,26 +161,35 @@ def dtw_band_kernel(a_ref, b_ref, o_ref, *, length: int, window: int,
 # Band-compressed kernel
 # ---------------------------------------------------------------------------
 
-def _prefix_sum(x: jnp.ndarray, length: int) -> jnp.ndarray:
-    """Inclusive prefix sum along axis 1 — log-depth shifted adds (rolls +
-    masks only, so it lowers inside a Pallas kernel body; no cumsum
-    primitive)."""
+def _prefix_sum(x: jnp.ndarray, length: int,
+                reverse: bool = False) -> jnp.ndarray:
+    """Inclusive prefix sum along axis 1 (suffix sum with ``reverse``) —
+    log-depth shifted adds (rolls + masks only, so it lowers inside a
+    Pallas kernel body; no cumsum primitive).  The suffix sum of a
+    reversed row performs the mirrored additions of the prefix sum of the
+    row, so it equals the reversed prefix sum bit for bit."""
     t = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
     shift = 1
     while shift < length:
-        x = x + jnp.where(t >= shift, jnp.roll(x, shift, axis=1), 0.0)
+        if reverse:
+            moved = jnp.where(t < length - shift,
+                              jnp.roll(x, -shift, axis=1), 0.0)
+        else:
+            moved = jnp.where(t >= shift, jnp.roll(x, shift, axis=1), 0.0)
+        x = x + moved
         shift *= 2
     return x
 
 
-def wavefront_compressed(a: jnp.ndarray, b: jnp.ndarray, *, length: int,
+def wavefront_compressed(a: jnp.ndarray, b_rev: jnp.ndarray, *, length: int,
                          window: int, width: int,
                          measure: MeasureArg = None,
                          corridor=None) -> jnp.ndarray:
     """Band-compressed anti-diagonal sweep over zipped pair *arrays*.
 
-    ``a (rows, L)`` vs ``b (rows, L)`` -> ``(rows, 1)`` banded elastic cost
-    under ``measure`` (squared banded DTW by default).  This is the
+    ``a (rows, L)`` vs ``b (rows, L)``, the latter passed time-reversed as
+    ``b_rev = b[:, ::-1]`` -> ``(rows, 1)`` banded elastic cost under
+    ``measure`` (squared banded DTW by default).  This is the
     in-register DP shared by :func:`dtw_band_compressed_kernel`, the fused
     LB-cascade refine and the fused pre-align+encode kernel (which calls it
     on segment x centroid pairs it has just built in VMEM) — everything
@@ -170,7 +209,9 @@ def wavefront_compressed(a: jnp.ndarray, b: jnp.ndarray, *, length: int,
     ``(rows, width)``; the per-row base offsets turn the value windows into
     ``take_along_axis`` gathers and the predecessor shifts into per-row
     rotate-selects — no shapes depend on data.  With ``corridor=None`` the
-    static Sakoe-Chiba geometry is traced exactly as before.
+    static Sakoe-Chiba geometry is traced exactly as before.  The per-row
+    gathers do not lower on the TPU; the compiled routes refuse a corridor
+    (:class:`repro.kernels.common.CompiledRouteUnsupported`).
     """
     spec = measures.resolve(measure)
     L, w, W = length, window, width
@@ -184,35 +225,39 @@ def wavefront_compressed(a: jnp.ndarray, b: jnp.ndarray, *, length: int,
     inf = jnp.float32(_NEG_SAFE_INF)
     t = jax.lax.broadcasted_iota(jnp.int32, (rows, W), 1)
 
-    # Padded copies so the per-diagonal windows are plain dynamic slices:
-    #   a cells:  a[lo + t]              -> slice of a_pad at lo
+    # Lane-padded copies so the per-diagonal windows are rotate + slice:
+    #   a cells:  a[lo + t]              -> window of a_pad at lo
     #   b cells:  b[d - lo - t]
-    #           = b_rev[L-1-d+lo + t]    -> slice of b_rev_pad at L-1-d+lo
-    # (0 <= lo <= L-1 and 0 <= L-1-d+lo <= L-1 for every feasible diagonal.)
-    pad = jnp.zeros((rows, W), jnp.float32)
-    a_pad = jnp.concatenate([a, pad], axis=1)
-    b_rev_pad = jnp.concatenate([jnp.flip(b, axis=1), pad], axis=1)
+    #           = b_rev[L-1-d+lo + t]    -> window of b_rev_pad at L-1-d+lo
+    # (0 <= lo <= L-1 and 0 <= L-1-d+lo <= L-1 for every feasible diagonal,
+    # so every window ends inside the L + W padded lanes.)
+    def padded(x):
+        return _pad_lanes(x, min_width=L + W)
+
+    a_pad = padded(a)
+    b_rev_pad = padded(b_rev)
 
     if spec.uses_neighbors:
         # a_{i-1} / b_{j-1} values (sentinel = element 0 at the borders,
-        # where the corresponding move reads an inf predecessor anyway)
+        # where the corresponding move reads an inf predecessor anyway);
+        # reversed, b_{j-1} is b_rev shifted one lane left
         a_prev = jnp.concatenate([a[:, :1], a[:, :-1]], axis=1)
-        b_prev = jnp.concatenate([b[:, :1], b[:, :-1]], axis=1)
-        a_prev_pad = jnp.concatenate([a_prev, pad], axis=1)
-        b_prev_rev_pad = jnp.concatenate([jnp.flip(b_prev, axis=1), pad],
-                                         axis=1)
+        b_prev_rev = jnp.concatenate([b_rev[:, 1:], b_rev[:, -1:]], axis=1)
+        a_prev_pad = padded(a_prev)
+        b_prev_rev_pad = padded(b_prev_rev)
     if spec.uses_gap_border:
-        # virtual first column/row: T[i, -1] = ga[i], T[-1, j] = gb[j]
+        # virtual first column/row: T[i, -1] = ga[i], T[-1, j] = gb[j];
+        # gb reversed is the suffix sum of b_rev's gap costs
         ga = _prefix_sum(measures.gap_costs(spec, a), L)
-        gb = _prefix_sum(measures.gap_costs(spec, b), L)
+        gb_rev = _prefix_sum(measures.gap_costs(spec, b_rev), L,
+                             reverse=True)
         zero = jnp.zeros((rows, 1), jnp.float32)
         ga_prev = jnp.concatenate([zero, ga[:, :-1]], axis=1)
-        gb_prev = jnp.concatenate([zero, gb[:, :-1]], axis=1)
-        ga_pad = jnp.concatenate([ga, pad], axis=1)
-        ga_prev_pad = jnp.concatenate([ga_prev, pad], axis=1)
-        gb_rev_pad = jnp.concatenate([jnp.flip(gb, axis=1), pad], axis=1)
-        gb_prev_rev_pad = jnp.concatenate([jnp.flip(gb_prev, axis=1), pad],
-                                          axis=1)
+        gb_prev_rev = jnp.concatenate([gb_rev[:, 1:], zero], axis=1)
+        ga_pad = padded(ga)
+        ga_prev_pad = padded(ga_prev)
+        gb_rev_pad = padded(gb_rev)
+        gb_prev_rev_pad = padded(gb_prev_rev)
 
     def lo_of(d):
         # max(0, d - (L-1), ceil((d - w) / 2)); jnp // is floor division.
@@ -246,7 +291,7 @@ def wavefront_compressed(a: jnp.ndarray, b: jnp.ndarray, *, length: int,
             s2 = lo - lo_of(d - 2) - 1
 
             def fetch(arr, base):
-                return jax.lax.dynamic_slice_in_dim(arr, base, W, axis=1)
+                return _window(arr, base, W)
         off_b = L - 1 - d + lo
 
         av = fetch(a_pad, lo)
@@ -287,36 +332,57 @@ def wavefront_compressed(a: jnp.ndarray, b: jnp.ndarray, *, length: int,
         diag = jnp.minimum(diag, inf)
         return diag, prev1
 
-    init = (jnp.full((rows, W), inf), jnp.full((rows, W), inf))
-    last, _ = jax.lax.fori_loop(0, 2 * L - 1, step, init)
+    init = carry_full((rows, W), _NEG_SAFE_INF)
+    last, _ = jax.lax.fori_loop(0, 2 * L - 1, step, (init, init))
     # Diagonal 2L-2 has lo = L-1: cell (L-1, L-1) sits in slot 0.
     return last[:, 0:1]
 
 
-def dtw_band_compressed_kernel(a_ref, b_ref, o_ref, *, length: int,
+def dtw_band_compressed_kernel(a_ref, b_rev_ref, o_ref, *, length: int,
                                window: int, block: int, width: int,
-                               broadcast_b: bool = False,
                                measure: MeasureArg = None):
-    """Kernel body: ``a_ref (block, L)`` and ``b_ref (block, L)`` (or
-    ``(1, L)`` with ``broadcast_b``) -> ``o_ref (block, 1)``.
+    """Kernel body: ``a_ref (block, L)`` and ``b_rev_ref (block, L)`` (time
+    reversed) -> ``o_ref (block, 1)``.
 
     Registers are ``(block, width)`` — only the feasible band cells of each
     anti-diagonal are materialized.
     """
     a = a_ref[...].astype(jnp.float32)
-    b = b_ref[...].astype(jnp.float32)
-    if broadcast_b:
-        b = jnp.broadcast_to(b, (block, length))
-    o_ref[...] = wavefront_compressed(a, b, length=length, window=window,
+    b_rev = b_rev_ref[...].astype(jnp.float32)
+    o_ref[...] = wavefront_compressed(a, b_rev, length=length, window=window,
                                       width=width, measure=measure)
 
 
-def dtw_band_adaptive_kernel(a_ref, b_ref, lo_ref, hi_ref, o_ref, *,
+def dtw_band_cdist_kernel(a_ref, b_rev_ref, o_ref, *, length: int,
+                          window: int, block_a: int, block_b: int,
+                          width: int, measure: MeasureArg = None):
+    """All-pairs tile: ``a_ref (block_a, L)`` x ``b_rev_ref (block_b, L)``
+    (time reversed) -> ``o_ref (block_a, block_b)``.
+
+    One band-compressed sweep per B row, broadcast against the A tile; each
+    sweep's ``(block_a, 1)`` column is selected into the lane-dense output
+    tile, which is stored once.
+    """
+    a = a_ref[...].astype(jnp.float32)
+    col = jax.lax.broadcasted_iota(jnp.int32, (block_a, block_b), 1)
+
+    def one_row(j, out):
+        b_rev = jnp.broadcast_to(
+            b_rev_ref[pl.ds(j, 1), :].astype(jnp.float32), (block_a, length))
+        d = wavefront_compressed(a, b_rev, length=length, window=window,
+                                 width=width, measure=measure)
+        return jnp.where(col == j, d, out)
+
+    o_ref[...] = jax.lax.fori_loop(0, block_b, one_row,
+                                   carry_full((block_a, block_b), 0.0))
+
+
+def dtw_band_adaptive_kernel(a_ref, b_rev_ref, lo_ref, hi_ref, o_ref, *,
                              length: int, window: int, block: int,
                              width: int, measure: MeasureArg = None):
-    """Adaptive-corridor kernel body: ``a_ref (block, L)``, ``b_ref
-    (block, L)`` plus per-pair corridor envelopes ``lo_ref``/``hi_ref``
-    ``(block, 2L-1)`` int32 -> ``o_ref (block, 1)``.
+    """Adaptive-corridor kernel body: ``a_ref (block, L)``, ``b_rev_ref
+    (block, L)`` (time reversed) plus per-pair corridor envelopes
+    ``lo_ref``/``hi_ref (block, 2L-1)`` int32 -> ``o_ref (block, 1)``.
 
     Same band-compressed registers as the static kernel, but the live cell
     range of every anti-diagonal comes from the pair's own corridor (built
@@ -324,9 +390,9 @@ def dtw_band_adaptive_kernel(a_ref, b_ref, lo_ref, hi_ref, o_ref, *,
     static ``window + 1`` when alignment paths hug the diagonal.
     """
     a = a_ref[...].astype(jnp.float32)
-    b = b_ref[...].astype(jnp.float32)
+    b_rev = b_rev_ref[...].astype(jnp.float32)
     o_ref[...] = wavefront_compressed(
-        a, b, length=length, window=window, width=width, measure=measure,
+        a, b_rev, length=length, window=window, width=width, measure=measure,
         corridor=(lo_ref[...], hi_ref[...]))
 
 
@@ -338,7 +404,8 @@ def make_dtw_band_call(n_pairs: int, length: int, window: Optional[int],
                        block: int, interpret: bool, mode: str = "compressed",
                        lane: int = 8, measure: MeasureArg = None,
                        width: Optional[int] = None):
-    """Build the pallas_call for ``(n_pairs, L)`` zipped pair batches.
+    """Build the pallas_call for ``(n_pairs, L)`` zipped pair batches, the
+    second operand time reversed.
 
     ``n_pairs`` must already be padded to a multiple of ``block``.
     ``mode`` selects the band-compressed sweep (default), the legacy
@@ -346,7 +413,8 @@ def make_dtw_band_call(n_pairs: int, length: int, window: Optional[int],
     adaptive-corridor sweep (``mode="adaptive"``, which adds two
     ``(n_pairs, 2L-1)`` int32 corridor operands and requires an explicit
     register ``width`` — normally the tuned adaptive width, see
-    :mod:`repro.kernels.tune`).
+    :mod:`repro.kernels.tune`).  The adaptive sweep's per-row gathers do
+    not lower on the TPU, so it is refused unless ``interpret``.
     """
     spec = measures.resolve(measure)
     w = effective_window(length, window)
@@ -372,6 +440,9 @@ def make_dtw_band_call(n_pairs: int, length: int, window: Optional[int],
         if width is None:
             raise ValueError("mode='adaptive' needs an explicit width "
                              "(the corridor cap)")
+        if not interpret:
+            raise CompiledRouteUnsupported("dtw_band with an adaptive "
+                                           "corridor")
         kernel = functools.partial(dtw_band_adaptive_kernel, length=length,
                                    window=w, block=block, width=width,
                                    measure=spec)
@@ -395,27 +466,31 @@ def make_dtw_band_cdist_call(n_a: int, n_b: int, length: int,
                              window: Optional[int], block_a: int,
                              interpret: bool, lane: int = 8,
                              measure: MeasureArg = None):
-    """All-pairs call on a 2-D grid: ``A (n_a, L) x B (n_b, L) -> (n_a, n_b)``.
+    """All-pairs call on a 2-D grid: ``A (n_a, L) x B_rev (n_b, L) -> (n_a,
+    n_b)`` with B passed time reversed.
 
-    Each grid step sweeps ``block_a`` rows of A against ONE row of B
-    (broadcast inside the kernel), so the N*M cross-product is never
-    materialized in HBM.  ``n_a`` must be padded to a multiple of
-    ``block_a``.
+    Each grid step sweeps ``block_a`` rows of A against ``block_b =
+    min(n_b, 128)`` rows of B, one B row at a time broadcast inside the
+    kernel, so the N*M cross-product is never materialized in HBM and the
+    output tile is lane dense.  ``n_a`` must be padded to a multiple of
+    ``block_a`` and ``n_b`` to a multiple of ``block_b``.
     """
     w = effective_window(length, window)
-    kernel = functools.partial(dtw_band_compressed_kernel, length=length,
-                               window=w, block=block_a,
+    block_b = min(n_b, _LANES)
+    if n_b % block_b:
+        raise ValueError(f"n_b={n_b} must be a multiple of {block_b}")
+    kernel = functools.partial(dtw_band_cdist_kernel, length=length,
+                               window=w, block_a=block_a, block_b=block_b,
                                width=band_width(length, w, lane),
-                               broadcast_b=True,
                                measure=measures.resolve(measure))
     return pl.pallas_call(
         kernel,
-        grid=(n_a // block_a, n_b),
+        grid=(n_a // block_a, n_b // block_b),
         in_specs=[
             pl.BlockSpec((block_a, length), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, length), lambda i, j: (j, 0)),
+            pl.BlockSpec((block_b, length), lambda i, j: (j, 0)),
         ],
-        out_specs=pl.BlockSpec((block_a, 1), lambda i, j: (i, j)),
+        out_specs=pl.BlockSpec((block_a, block_b), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((n_a, n_b), jnp.float32),
         interpret=interpret,
     )

@@ -29,16 +29,17 @@ def _backend_name(interpret: bool) -> str:
 
 def _tuned_block(op: str, block: Optional[int], *, length: int,
                  window: Optional[int], measure: MeasureArg,
-                 interpret: bool, param: str = "block",
-                 default: int = 8) -> int:
+                 interpret: bool, param: str = "block") -> int:
     """``block=None`` consults the tuning table (a trace-time Python
-    resolution — the result is a static launch parameter); an explicit
-    block always wins."""
+    resolution — the result is a static launch parameter), falling back
+    to the backend's builtin block; an explicit block always wins."""
     if block is not None:
         return block
+    backend = _backend_name(interpret)
     return tune.tuned(op, param, length=length, window=window,
                       measure=measures.resolve(measure).name,
-                      backend=_backend_name(interpret), default=default)
+                      backend=backend,
+                      default=tune.default_block(op, backend))
 
 
 @functools.partial(jax.jit,
@@ -81,7 +82,7 @@ def dtw_band(A: jnp.ndarray, B: jnp.ndarray, window: Optional[int] = None,
     block = _tuned_block("dtw_band", block, length=L, window=window,
                          measure=measure, interpret=interpret)
     Ap = pad_to(A, block, axis=0)
-    Bp = pad_to(B, block, axis=0)
+    Bp = pad_to(jnp.flip(B, axis=1), block, axis=0)   # kernels take B reversed
     call = make_dtw_band_call(Ap.shape[0], L, window, block, interpret,
                               mode=mode, lane=lane, measure=measure,
                               width=width)
@@ -104,10 +105,10 @@ def dtw_band_cdist(A: jnp.ndarray, B: jnp.ndarray,
                    measure: MeasureArg = None) -> jnp.ndarray:
     """All-pairs banded elastic cost: ``A (N, L)``, ``B (M, L)`` -> ``(N, M)``.
 
-    Runs the band-compressed kernel on a 2-D grid (A row-blocks x B rows);
-    the N*M cross-product is never materialized — B rows are broadcast
-    inside the kernel tile.  ``block=None`` consults the tuning table
-    (``block_a``).
+    Runs the band-compressed kernel on a 2-D grid (A row-blocks x B
+    row-blocks of up to 128); the N*M cross-product is never materialized —
+    B rows are broadcast inside the kernel tile.  ``block=None`` consults
+    the tuning table (``block_a``).
     """
     if interpret is None:
         interpret = default_interpret()
@@ -121,6 +122,12 @@ def dtw_band_cdist(A: jnp.ndarray, B: jnp.ndarray,
                          measure=measure, interpret=interpret,
                          param="block_a")
     Ap = pad_to(A, block, axis=0)
-    call = make_dtw_band_cdist_call(Ap.shape[0], M, L, window, block,
-                                    interpret, lane=lane, measure=measure)
-    return call(Ap, B)[:N]
+    # kernels take B reversed; more than one lane tile of B rows is padded
+    # to whole tiles
+    Bp = jnp.flip(B, axis=1)
+    if M > 128:
+        Bp = pad_to(Bp, 128, axis=0)
+    call = make_dtw_band_cdist_call(Ap.shape[0], Bp.shape[0], L, window,
+                                    block, interpret, lane=lane,
+                                    measure=measure)
+    return call(Ap, Bp)[:N, :M]

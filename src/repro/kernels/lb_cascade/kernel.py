@@ -37,6 +37,7 @@ from jax.experimental import pallas as pl
 from ...core.dispatch import effective_window
 from ...core.lb import lb_keogh, lb_kim
 from ...core.measures import MeasureArg
+from ..common import CompiledRouteUnsupported
 from ..dtw_band.kernel import band_width, wavefront_compressed
 
 __all__ = [
@@ -46,13 +47,14 @@ __all__ = [
 ]
 
 
-def lb_cascade_kernel(a_ref, b_ref, u_ref, l_ref, t_ref, d_ref, f_ref, *,
-                      length: int, window: int, block: int, width: int,
-                      measure: MeasureArg = None):
-    """``a_ref (block, L)`` queries, ``b_ref (block, L)`` candidates,
-    ``u_ref``/``l_ref (block, L)`` query envelopes, ``t_ref (block, 1)``
-    thresholds -> ``d_ref (block, 1)`` distances, ``f_ref (block, 1)``
-    refined flags (int32 0/1)."""
+def lb_cascade_kernel(a_ref, b_ref, br_ref, u_ref, l_ref, t_ref, d_ref,
+                      f_ref, *, length: int, window: int, block: int,
+                      width: int, measure: MeasureArg = None):
+    """``a_ref (block, L)`` queries, ``b_ref (block, L)`` candidates and
+    ``br_ref`` the same candidates time reversed (the bounds read ``b``,
+    the sweep reads ``b_rev``), ``u_ref``/``l_ref (block, L)`` query
+    envelopes, ``t_ref (block, 1)`` thresholds -> ``d_ref (block, 1)``
+    distances, ``f_ref (block, 1)`` refined flags (int32 0/1)."""
     a = a_ref[...].astype(jnp.float32)
     b = b_ref[...].astype(jnp.float32)
     up = u_ref[...].astype(jnp.float32)
@@ -65,7 +67,8 @@ def lb_cascade_kernel(a_ref, b_ref, u_ref, l_ref, t_ref, d_ref, f_ref, *,
     surv = lb < thresh                                 # (block, 1)
 
     def refine(_):
-        return wavefront_compressed(a, b, length=length, window=window,
+        return wavefront_compressed(a, br_ref[...].astype(jnp.float32),
+                                    length=length, window=window,
                                     width=width, measure=measure)
 
     def skip(_):
@@ -76,8 +79,8 @@ def lb_cascade_kernel(a_ref, b_ref, u_ref, l_ref, t_ref, d_ref, f_ref, *,
     f_ref[...] = surv.astype(jnp.int32)
 
 
-def lb_cascade_adaptive_kernel(a_ref, b_ref, u_ref, l_ref, t_ref, lo_ref,
-                               hi_ref, d_ref, f_ref, *, length: int,
+def lb_cascade_adaptive_kernel(a_ref, b_ref, br_ref, u_ref, l_ref, t_ref,
+                               lo_ref, hi_ref, d_ref, f_ref, *, length: int,
                                window: int, block: int, width: int,
                                measure: MeasureArg = None):
     """Adaptive-corridor cascade tile: the static kernel plus per-pair
@@ -97,7 +100,8 @@ def lb_cascade_adaptive_kernel(a_ref, b_ref, u_ref, l_ref, t_ref, lo_ref,
     surv = lb < thresh                                 # (block, 1)
 
     def refine(_):
-        return wavefront_compressed(a, b, length=length, window=window,
+        return wavefront_compressed(a, br_ref[...].astype(jnp.float32),
+                                    length=length, window=window,
                                     width=width, measure=measure,
                                     corridor=(lo_ref[...], hi_ref[...]))
 
@@ -116,16 +120,22 @@ def make_lb_refine_call(n_pairs: int, length: int, window: Optional[int],
     """Build the pallas_call over ``(n_pairs, L)`` zipped pair batches.
 
     ``n_pairs`` must already be padded to a multiple of ``block``.
-    ``adaptive=True`` adds two ``(n_pairs, 2L-1)`` int32 corridor
-    operands and requires an explicit register ``width``.
+    Operands: queries, candidates, candidates time reversed, the two
+    envelopes and the ``(n_pairs, 1)`` thresholds.  ``adaptive=True`` adds
+    two ``(n_pairs, 2L-1)`` int32 corridor operands and requires an
+    explicit register ``width``; its per-row gathers do not lower on the
+    TPU, so it is refused unless ``interpret``.
     """
     w = effective_window(length, window)
     if width is None:
         width = band_width(length, w, lane)
     row_spec = pl.BlockSpec((block, length), lambda i: (i, 0))
     out_spec = pl.BlockSpec((block, 1), lambda i: (i, 0))
-    in_specs = [row_spec, row_spec, row_spec, row_spec, out_spec]
+    in_specs = [row_spec, row_spec, row_spec, row_spec, row_spec, out_spec]
     if adaptive:
+        if not interpret:
+            raise CompiledRouteUnsupported(
+                "lb_refine with an adaptive corridor")
         kernel = functools.partial(lb_cascade_adaptive_kernel, length=length,
                                    window=w, block=block, width=width,
                                    measure=measure)

@@ -61,7 +61,8 @@ def lb_refine(A: jnp.ndarray, B: jnp.ndarray, upper: jnp.ndarray,
     if block is None:
         block = tune.tuned("lb_refine", "block", length=L, window=window,
                            measure=measures.resolve(measure).name,
-                           backend=backend, default=8)
+                           backend=backend,
+                           default=tune.default_block("lb_refine", backend))
     adaptive = corridor is not None
     if adaptive and width is None:
         width = tune.adaptive_width(L, window, lane,
@@ -69,6 +70,7 @@ def lb_refine(A: jnp.ndarray, B: jnp.ndarray, upper: jnp.ndarray,
                                     backend=backend)
     Ap = pad_to(A, block, axis=0)
     Bp = pad_to(B, block, axis=0)
+    Brp = jnp.flip(Bp, axis=1)        # the refine sweep takes B reversed
     Up = pad_to(jnp.asarray(upper, jnp.float32), block, axis=0)
     Lp = pad_to(jnp.asarray(lower, jnp.float32), block, axis=0)
     # padded rows never refine: their threshold is -inf
@@ -79,9 +81,9 @@ def lb_refine(A: jnp.ndarray, B: jnp.ndarray, upper: jnp.ndarray,
                                adaptive=adaptive, width=width)
     if adaptive:
         lo, hi = corridor
-        d, flag = call(Ap, Bp, Up, Lp, Tp,
+        d, flag = call(Ap, Bp, Brp, Up, Lp, Tp,
                        pad_to(lo.astype(jnp.int32), block, axis=0),
                        pad_to(hi.astype(jnp.int32), block, axis=0))
     else:
-        d, flag = call(Ap, Bp, Up, Lp, Tp)
+        d, flag = call(Ap, Bp, Brp, Up, Lp, Tp)
     return d[:n, 0], flag[:n, 0].astype(bool)

@@ -74,6 +74,7 @@ def lb_refine_jax(A: jnp.ndarray, B: jnp.ndarray, upper: jnp.ndarray,
                            jnp.asarray(lower, jnp.float32))
     if width is None:
         width = band_width(L, w)
-    d = wavefront_compressed(A, B, length=L, window=w, width=width,
-                             measure=measure, corridor=corridor)[:, 0]
+    d = wavefront_compressed(A, jnp.flip(B, axis=1), length=L, window=w,
+                             width=width, measure=measure,
+                             corridor=corridor)[:, 0]
     return _select(lb, d, jnp.asarray(thresh, jnp.float32))

@@ -17,11 +17,12 @@ tile, so segments only ever exist in VMEM:
      static, so the tail window ``[l - t, l]`` is ``t + 1`` static column
      reads; the right-most change point wins (masked min over offsets).
   4. *Segment gather + linear re-interpolation* — data-dependent boundaries
-     become per-row fractional positions; two lane gathers
-     (``take_along_axis``) plus a lerp resample each segment to the static
-     length ``S = L/M + t``.
+     become per-row fractional positions; two one-hot lane selects (a
+     masked max over the ``L`` lanes, exact) plus a lerp resample each
+     segment to the static length ``S = L/M + t``.
   5. *Encode* — the ``(block, K)`` pair block per subspace is swept with the
-     band-compressed DTW wavefront shared with :mod:`..dtw_band.kernel`;
+     band-compressed DTW wavefront shared with :mod:`..dtw_band.kernel`
+     (the centroids arrive time reversed, as the wavefront takes them);
      codes are the per-row argmin (first-index tie-break, matching
      ``jnp.argmin``).
 
@@ -56,12 +57,21 @@ def _forward_fill_sign(s: jnp.ndarray, t: jnp.ndarray,
     return s
 
 
+def _lane_select(x: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
+    """``take_along_axis(x, idx, axis=1)`` for ``x (rows, L)`` and in-range
+    ``idx (rows, S)``, as a one-hot masked max over the ``L`` lanes — exact,
+    and it lowers where a lane gather of another shape does not."""
+    lanes = jax.lax.broadcasted_iota(jnp.int32, idx.shape + x.shape[1:], 2)
+    hit = lanes == idx[:, :, None]
+    return jnp.max(jnp.where(hit, x[:, None, :], -jnp.inf), axis=2)
+
+
 def prealign_encode_kernel(x_ref, c_ref, lin_ref, o_ref, *, length: int,
                            n_sub: int, n_k: int, seg_len: int, level: int,
                            tail: int, window: int, block: int, width: int,
                            measure: MeasureArg = None):
-    """``x_ref (block, L)``, ``c_ref (M, K, S)``, ``lin_ref (1, S)`` ->
-    ``o_ref (block, M)`` int32 codes."""
+    """``x_ref (block, L)``, ``c_ref (M, K, S)`` centroids time reversed,
+    ``lin_ref (1, S)`` -> ``o_ref (block, M)`` int32 codes."""
     L, M, K, S = length, n_sub, n_k, seg_len
     x = x_ref[...].astype(jnp.float32)
     lin = lin_ref[...].astype(jnp.float32)            # linspace(0, 1, S)
@@ -82,11 +92,12 @@ def prealign_encode_kernel(x_ref, c_ref, lin_ref, o_ref, *, length: int,
     bounds = [jnp.zeros((block, 1), jnp.int32)]
     for m in range(1, M):
         l = m * seg
-        cand = [change[:, c:c + 1] if c >= 1 else
-                jnp.zeros((block, 1), bool) for c in range(l, l - tail - 1, -1)]
-        ok = jnp.concatenate(cand, axis=1)            # (block, tail + 1)
-        offs = jax.lax.broadcasted_iota(jnp.int32, (block, tail + 1), 1)
-        first = jnp.min(jnp.where(ok, offs, tail + 1), axis=1, keepdims=True)
+        # smallest offset o <= tail with a change point at l - o (selects
+        # from the far end inward, so the nearest change point wins)
+        first = jnp.full((block, 1), tail + 1, jnp.int32)
+        for o in range(tail, -1, -1):
+            if l - o >= 1:
+                first = jnp.where(change[:, l - o:l - o + 1], o, first)
         bounds.append(jnp.where(first <= tail, l - first, l).astype(jnp.int32))
     bounds.append(jnp.full((block, 1), L, jnp.int32))
 
@@ -98,11 +109,11 @@ def prealign_encode_kernel(x_ref, c_ref, lin_ref, o_ref, *, length: int,
         lo = jnp.clip(jnp.floor(pos).astype(jnp.int32), 0, L - 1)
         hi = jnp.clip(lo + 1, 0, L - 1)
         frac = pos - lo.astype(jnp.float32)
-        x_lo = jnp.take_along_axis(x, lo, axis=1)     # (block, S)
-        x_hi = jnp.take_along_axis(x, hi, axis=1)
+        x_lo = _lane_select(x, lo)                    # (block, S)
+        x_hi = _lane_select(x, hi)
         segm = x_lo * (1.0 - frac) + x_hi * frac
 
-        cents = c_ref[m]                              # (K, S)
+        cents = c_ref[m]                              # (K, S) reversed
         a = jnp.broadcast_to(segm[:, None, :], (block, K, S))
         b = jnp.broadcast_to(cents[None, :, :], (block, K, S))
         d = wavefront_compressed(a.reshape(block * K, S),

@@ -64,4 +64,5 @@ def prealign_encode(X: jnp.ndarray, centroids: jnp.ndarray, level: int,
     call = make_prealign_encode_call(
         Xp.shape[0], D, M, K, S, level, tail, w, block,
         band_width(S, w, lane), interpret, measure=measure)
-    return call(Xp, centroids, lin)[:N]
+    # the kernel's wavefront takes the centroids time reversed
+    return call(Xp, jnp.flip(centroids, axis=2), lin)[:N]

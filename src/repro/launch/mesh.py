@@ -32,9 +32,14 @@ def make_search_mesh(n_devices: int | None = None):
     search splits the sealed inverted lists across it (queries
     replicated, partial top-k fanned in with an ``all_gather``).
     Degenerates to a 1-device mesh on CPU, where the planner's shard_map
-    path is bit-identical to the plain vmap path."""
+    path is bit-identical to the plain vmap path.
+
+    The axis is ``Auto``: the planner slices and pads its eager outputs
+    outside ``shard_map``, which an ``Explicit`` axis (``jax.make_mesh``'s
+    default) would refuse with a sharding-type error."""
     n = n_devices if n_devices is not None else len(jax.devices())
-    return jax.make_mesh((n,), ("search",))
+    return jax.make_mesh((n,), ("search",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
 
 
 def validate_search_mesh(mesh, n_shards: int) -> None:

@@ -15,11 +15,13 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 import pytest
+from jax._src.pallas.mosaic.error_handling import MosaicError
 
 from repro.core import corridor as corr
 from repro.core import dispatch
 from repro.core.lb import keogh_envelope
 from repro.core.lb_search import filtered_topk
+from repro.kernels.common import CompiledRouteUnsupported
 from repro.kernels import tune
 
 from conftest import dtw_reference
@@ -315,22 +317,67 @@ def test_tune_auto_benchmarks_and_memoizes(tmp_path, monkeypatch):
                       default=8) == 8
 
 
-def test_tuned_is_noop_inside_trace(monkeypatch):
+def test_tuned_is_noop_inside_trace(tmp_path, monkeypatch):
     # block resolution happens at trace time; mid-trace the tuner must
     # fall back to defaults instead of launching benchmark kernels
     monkeypatch.setenv(tune.ENV, "auto")
     monkeypatch.setenv(tune.GRID_ENV, "minimal")
+    monkeypatch.setenv(tune.OUT_ENV, str(tmp_path))
     tune.reset()
     seen = []
 
+    def no_measure(*args, **kwargs):
+        raise AssertionError("benchmarked inside a trace")
+
+    monkeypatch.setattr(tune, "_measure", no_measure)
+    assert tune._trace_clean()
+
     @jax.jit
     def f(x):
+        assert not tune._trace_clean()
         seen.append(tune.tuned("dtw_band", "block", length=64, window=6,
                                default=8))
         return x
 
     f(jnp.zeros(3))
     assert seen == [8]
+    assert not (tmp_path / "tuning.json").exists()
+
+
+def _failing_measure(err):
+    """Every candidate but the smallest block fails with ``err``."""
+    def measure(op, params, **kwargs):
+        if params["block"] != 8:
+            raise err
+        return 1.0
+    return measure
+
+
+@pytest.mark.parametrize("err", [
+    CompiledRouteUnsupported("a test kernel"),
+    MosaicError("Mosaic failed to compile TPU kernel"),
+], ids=["no_compiled_kernel", "mosaic_refused"])
+def test_tune_skips_candidates_that_do_not_compile(tmp_path, monkeypatch,
+                                                   err):
+    monkeypatch.setenv(tune.ENV, "auto")
+    monkeypatch.delenv(tune.GRID_ENV, raising=False)
+    monkeypatch.setenv(tune.OUT_ENV, str(tmp_path))
+    tune.reset()
+    monkeypatch.setattr(tune, "_measure", _failing_measure(err))
+    assert tune.tuned("dtw_band", "block", length=64, window=6,
+                      default=32) == 8
+
+
+def test_tune_reraises_errors_other_than_compile_errors(tmp_path,
+                                                        monkeypatch):
+    monkeypatch.setenv(tune.ENV, "auto")
+    monkeypatch.delenv(tune.GRID_ENV, raising=False)
+    monkeypatch.setenv(tune.OUT_ENV, str(tmp_path))
+    tune.reset()
+    monkeypatch.setattr(tune, "_measure",
+                        _failing_measure(TypeError("a bug, not a compile")))
+    with pytest.raises(TypeError, match="a bug"):
+        tune.tuned("dtw_band", "block", length=64, window=6, default=32)
 
 
 def test_adaptive_width_is_lane_aligned_and_capped():

@@ -100,6 +100,39 @@ def test_dtw_band_cdist_no_materialize_grid():
         np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
 
 
+def test_dtw_band_cdist_pads_b_to_lane_tiles():
+    """More than 128 B rows: B is padded to whole 128-row tiles and the
+    padded output columns are sliced off."""
+    rng = np.random.default_rng(13)
+    A = rng.standard_normal((3, 10)).astype(np.float32)
+    B = rng.standard_normal((130, 10)).astype(np.float32)
+    got = np.asarray(dtw_band_cdist(A, B, 2, interpret=True))
+    assert got.shape == (3, 130)
+    np.testing.assert_allclose(got, np.asarray(dtw_band_cdist_ref(A, B, 2)),
+                               rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("op", ["dtw_band", "lb_refine"])
+def test_adaptive_corridor_refused_on_compiled_route(op):
+    """The adaptive sweep's per-row gathers have no TPU lowering: asking
+    for it with interpret=False fails at trace time with a named error
+    instead of taking another route."""
+    from repro.core import corridor as corr
+    from repro.kernels.common import CompiledRouteUnsupported
+    from repro.kernels.lb_cascade.ops import lb_refine
+    rng = np.random.default_rng(14)
+    A = rng.standard_normal((8, 16)).astype(np.float32)
+    B = rng.standard_normal((8, 16)).astype(np.float32)
+    band = corr.static_band(16, 3)
+    lo, hi = (jnp.broadcast_to(x, (8, 31)) for x in band)
+    with pytest.raises(CompiledRouteUnsupported, match="adaptive corridor"):
+        if op == "dtw_band":
+            dtw_band(A, B, 3, interpret=False, corridor=(lo, hi), width=8)
+        else:
+            lb_refine(A, B, A, A, np.zeros(8, np.float32), 3,
+                      interpret=False, corridor=(lo, hi), width=8)
+
+
 # ---------------------------------------------------------------------------
 # pq_adc
 # ---------------------------------------------------------------------------
@@ -285,10 +318,17 @@ def _lb_setup(n, L, window, seed):
 @pytest.mark.parametrize("n,L", [(1, 8), (7, 16), (13, 32), (32, 24)])
 @pytest.mark.parametrize("window", [None, 2, 5])
 def test_lb_cascade_matches_ref(n, L, window):
-    """Mixed thresholds: some tiles refine, some are fully pruned."""
+    """Mixed thresholds: some tiles refine, some are fully pruned.  The
+    threshold sits halfway between the two middle bounds, so no pair's
+    bound equals it and an ulp of summation-order difference between the
+    kernel's bound and the reference's cannot flip a comparison."""
     A, B, up, lo = _lb_setup(n, L, window, n * 37 + L)
-    lb = np.asarray(cascade_bound_ref(A, B, up, lo))
-    thresh = np.full(n, np.median(lb) if n > 1 else lb[0] + 1.0, np.float32)
+    lb = np.sort(np.asarray(cascade_bound_ref(A, B, up, lo)))
+    k = n // 2
+    if n > 1:
+        assert lb[k - 1] < lb[k]
+    mid = (lb[k - 1] + lb[k]) / 2 if n > 1 else lb[0] + 1.0
+    thresh = np.full(n, mid, np.float32)
     got_d, got_f = lb_refine_kernel(A, B, up, lo, thresh, window, block=4,
                                     interpret=True)
     want_d, want_f = lb_refine_ref(A, B, up, lo, thresh, window)
