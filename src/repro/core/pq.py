@@ -392,12 +392,15 @@ def query_lut_batch(q_segs: jnp.ndarray, cb: PQCodebook,
     (4, 2, 2)
     """
     Nq, M, S = q_segs.shape
-    if euclidean:
-        return jnp.sum(
-            (q_segs[:, :, None, :] - cb.centroids[None]) ** 2, -1)
-    return jnp.stack([elastic_cdist(q_segs[:, m], cb.centroids[m], window,
-                                    measure=measure)
-                      for m in range(M)], axis=1)
+    # every search plan builds its query tables here: the scope names this
+    # device work as the search's LUT stage in a profile
+    with jax.named_scope("index.search.lut"):
+        if euclidean:
+            return jnp.sum(
+                (q_segs[:, :, None, :] - cb.centroids[None]) ** 2, -1)
+        return jnp.stack([elastic_cdist(q_segs[:, m], cb.centroids[m],
+                                        window, measure=measure)
+                          for m in range(M)], axis=1)
 
 
 @jax.jit

@@ -120,7 +120,8 @@ def _rank_segment(codes, ids, live, list_start, list_len, dc, qluts, *,
     fine stage regardless of how many segments exist."""
     fn = lambda dcr, ql: fine_rank(codes, ids, list_start, list_len,
                                    max_list, dcr, ql, n_probe, k, live=live)
-    return jax.vmap(fn)(dc, qluts)
+    with jax.named_scope("index.search.fine"):
+        return jax.vmap(fn)(dc, qluts)
 
 
 @functools.partial(jax.jit, static_argnames=("window", "k", "euclidean",
@@ -149,46 +150,48 @@ def _scan_hot(data, ids, live, Q, q_valid=None, *, window: int, k: int,
     :func:`repro.core.lb_search.filtered_topk`; the default path compiles
     the exact pre-telemetry graph, so obs-off results stay bit-identical.
     """
-    if euclidean:
-        d2 = euclidean_sq(Q, data)
+    with jax.named_scope("index.search.hot"):
+        if euclidean:
+            d2 = euclidean_sq(Q, data)
+            dh = jnp.sqrt(jnp.maximum(d2, 0.0))
+            dh = jnp.where(live[None, :], dh, jnp.inf)           # (Nq, cap)
+            if q_valid is not None:
+                dh = jnp.where(q_valid[:, None], dh, jnp.inf)
+            neg, idx = jax.lax.top_k(-dh, k)
+            out_ids = jnp.where(jnp.isfinite(neg), ids[idx], -1)
+            if with_stats:
+                # no elastic cascade under the PQ_ED baseline: report an empty
+                # telemetry record rather than a fake 0% pruning rate
+                zero = jnp.zeros((), jnp.int32)
+                return -neg, out_ids, {"n_bounded": zero, "n_refined": zero,
+                                       "n_waves": zero,
+                                       "refined_per_wave": zero[None]}
+            return -neg, out_ids
+        d2, idx, st = filtered_topk(Q, data, window, k, valid=live,
+                                    measure=measure, q_valid=q_valid,
+                                    with_stats=with_stats, band=band)
         dh = jnp.sqrt(jnp.maximum(d2, 0.0))
-        dh = jnp.where(live[None, :], dh, jnp.inf)           # (Nq, cap)
-        if q_valid is not None:
-            dh = jnp.where(q_valid[:, None], dh, jnp.inf)
-        neg, idx = jax.lax.top_k(-dh, k)
-        out_ids = jnp.where(jnp.isfinite(neg), ids[idx], -1)
+        out_ids = jnp.where(idx >= 0, ids[jnp.maximum(idx, 0)], -1)
         if with_stats:
-            # no elastic cascade under the PQ_ED baseline: report an empty
-            # telemetry record rather than a fake 0% pruning rate
-            zero = jnp.zeros((), jnp.int32)
-            return -neg, out_ids, {"n_bounded": zero, "n_refined": zero,
-                                   "n_waves": zero,
-                                   "refined_per_wave": zero[None]}
-        return -neg, out_ids
-    d2, idx, st = filtered_topk(Q, data, window, k, valid=live,
-                                measure=measure, q_valid=q_valid,
-                                with_stats=with_stats, band=band)
-    dh = jnp.sqrt(jnp.maximum(d2, 0.0))
-    out_ids = jnp.where(idx >= 0, ids[jnp.maximum(idx, 0)], -1)
-    if with_stats:
-        return dh, out_ids, st
-    return dh, out_ids
+            return dh, out_ids, st
+        return dh, out_ids
 
 
 @functools.partial(jax.jit, static_argnames=("topk",))
 def _merge_topk(parts_d: Tuple[jnp.ndarray, ...],
                 parts_i: Tuple[jnp.ndarray, ...], *, topk: int):
-    all_d = jnp.concatenate(parts_d, axis=1)
-    all_i = jnp.concatenate(parts_i, axis=1)
-    missing = topk - all_d.shape[1]
-    if missing > 0:
-        Nq = all_d.shape[0]
-        all_d = jnp.concatenate(
-            [all_d, jnp.full((Nq, missing), jnp.inf)], 1)
-        all_i = jnp.concatenate(
-            [all_i, jnp.full((Nq, missing), -1, all_i.dtype)], 1)
-    neg, best = jax.lax.top_k(-all_d, topk)
-    return -neg, jnp.take_along_axis(all_i, best, axis=1)
+    with jax.named_scope("index.search.merge"):
+        all_d = jnp.concatenate(parts_d, axis=1)
+        all_i = jnp.concatenate(parts_i, axis=1)
+        missing = topk - all_d.shape[1]
+        if missing > 0:
+            Nq = all_d.shape[0]
+            all_d = jnp.concatenate(
+                [all_d, jnp.full((Nq, missing), jnp.inf)], 1)
+            all_i = jnp.concatenate(
+                [all_i, jnp.full((Nq, missing), -1, all_i.dtype)], 1)
+        neg, best = jax.lax.top_k(-all_d, topk)
+        return -neg, jnp.take_along_axis(all_i, best, axis=1)
 
 
 def search_impl(coarse: jnp.ndarray, cb: PQCodebook,
@@ -468,18 +471,22 @@ class StreamingIndex:
             if len(ids) == 0:
                 return
             Xj = jnp.asarray(rows)
-            codes = np.asarray(encode(Xj, self.cb, self.cfg.pq))
-            assign = np.asarray(coarse_assign(
-                Xj, self.coarse, self.cfg.coarse_window(self.dim),
-                self.cfg.pq.measure()))
+            with obs.span("index.flush.encode") as sp:
+                codes = np.asarray(sp.fence(encode(Xj, self.cb,
+                                                   self.cfg.pq)))
+            with obs.span("index.flush.assign") as sp:
+                assign = np.asarray(sp.fence(coarse_assign(
+                    Xj, self.coarse, self.cfg.coarse_window(self.dim),
+                    self.cfg.pq.measure())))
             cap = self.cfg.hot_capacity
-            # shard_round = ceil(cap / n_shards): every flush-born segment
-            # gets the same shard_cap regardless of list skew, so they all
-            # share one compiled fine-stage / planner shape
-            self._add_segment(seal(codes, ids, assign, self.cfg.n_lists,
-                                   rows=cap, max_list=cap,
-                                   n_shards=self.cfg.n_shards,
-                                   shard_round=-(-cap // self.cfg.n_shards)))
+            with obs.span("index.flush.seal") as sp:
+                # shard_round = ceil(cap / n_shards): every flush-born
+                # segment gets the same shard_cap regardless of list skew,
+                # so they all share one compiled fine-stage / planner shape
+                seg = seal(codes, ids, assign, self.cfg.n_lists, rows=cap,
+                           max_list=cap, n_shards=self.cfg.n_shards,
+                           shard_round=-(-cap // self.cfg.n_shards))
+                self._add_segment(sp.fence(seg))
         if obs.enabled():
             obs.counter("index_sealed_rows_total",
                         persistent=True).inc(len(ids))

@@ -16,6 +16,9 @@ metric *writes* stay cheap host-side dict/list operations either way, and
 the disabled path is strictly zero device overhead — no spans, no fences,
 no ``block_until_ready`` — so search results are bit-identical with obs
 on or off and the instrumentation is safe to keep in every hot path.
+Enabled, spans fence their device work by default; ``REPRO_OBS_FENCE=0``
+(or ``enable(fence=False)``) keeps every span and metric but makes the
+fences identities, so the traced code dispatches as the untraced code does.
 ``REPRO_OBS_DUMP=<path>`` writes a JSON snapshot at process exit;
 ``scripts/obs_report.py`` renders one as a console report.
 
@@ -33,15 +36,15 @@ from .registry import (DEFAULT_LATENCY_BUCKETS, MAX_SAMPLES, REGISTRY,
                        percentile)
 from .report import (check_stages, counter_value, missing_stages, render,
                      stage_rows)
-from .spans import (ENV_VAR, Span, current_spans, disable, enable, enabled,
-                    fence, override, span)
+from .spans import (ENV_VAR, FENCE_ENV_VAR, Span, current_spans, disable,
+                    enable, enabled, fence, fencing, override, span, wait)
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "Registry", "REGISTRY",
     "exp_buckets", "percentile", "DEFAULT_LATENCY_BUCKETS", "MAX_SAMPLES",
-    "ENV_VAR", "DUMP_ENV_VAR", "PROM_PREFIX",
-    "enabled", "enable", "disable", "override",
-    "span", "Span", "fence", "current_spans",
+    "ENV_VAR", "FENCE_ENV_VAR", "DUMP_ENV_VAR", "PROM_PREFIX",
+    "enabled", "fencing", "enable", "disable", "override",
+    "span", "Span", "fence", "wait", "current_spans",
     "counter", "gauge", "histogram", "reset",
     "snapshot", "to_json", "to_prometheus", "write_snapshot",
     "render", "stage_rows", "counter_value", "missing_stages",

@@ -47,6 +47,7 @@ def snapshot(registry: Optional[Registry] = None,
     reg = registry if registry is not None else REGISTRY
     out = {
         "obs_enabled": spans.enabled(),
+        "obs_fenced": spans.fencing(),
         "counters": [
             {"name": c.name, "labels": c.labels, "value": c.value}
             for c in reg.counters()],

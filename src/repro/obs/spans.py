@@ -18,7 +18,14 @@ pipeline):
 * enabled, :meth:`Span.fence` blocks on its argument (skipping tracers:
   fencing inside a traced computation is a no-op by construction), so
   async-dispatched device work is attributed to the span that launched
-  it instead of leaking into whichever stage happens to block next.
+  it instead of leaking into whichever stage happens to block next;
+* enabled with fencing off (``REPRO_OBS_FENCE=0`` or
+  ``enable(fence=False)``), spans, annotations and metrics are kept but
+  every fence is an identity: the instrumented code dispatches exactly as
+  it does with obs disabled, so a pipelined caller stays pipelined.  This
+  is the mode to leave on in production; device time per stage then comes
+  from the profiler trace (the jitted stage bodies carry
+  ``jax.named_scope`` names), not from the span's host time.
 
 Spans nest and re-enter freely: each ``with`` entry pushes onto a
 thread-local stack and records its own sample on exit, exceptions
@@ -39,12 +46,21 @@ import jax
 
 from .registry import REGISTRY
 
-__all__ = ["ENV_VAR", "enabled", "enable", "disable", "override", "span",
-           "current_spans", "fence", "Span"]
+__all__ = ["ENV_VAR", "FENCE_ENV_VAR", "enabled", "fencing", "enable",
+           "disable", "override", "span", "current_spans", "fence", "wait",
+           "Span"]
 
 ENV_VAR = "REPRO_OBS"
+FENCE_ENV_VAR = "REPRO_OBS_FENCE"
 
 _enabled = os.environ.get(ENV_VAR, "0").lower() not in ("", "0", "false")
+
+
+def _env_fence() -> bool:
+    return os.environ.get(FENCE_ENV_VAR, "1").lower() not in ("0", "false")
+
+
+_fence = _env_fence()
 
 _local = threading.local()
 
@@ -56,9 +72,17 @@ def enabled() -> bool:
     return _enabled
 
 
-def enable() -> None:
-    global _enabled
+def fencing() -> bool:
+    """True when enabled spans block on their device work."""
+    return _enabled and _fence
+
+
+def enable(fence: Optional[bool] = None) -> None:
+    """Turn obs on; ``fence`` sets whether spans block on their device
+    work (``None``: ``REPRO_OBS_FENCE``, fenced unless it is ``0``)."""
+    global _enabled, _fence
     _enabled = True
+    _fence = _env_fence() if fence is None else bool(fence)
 
 
 def disable() -> None:
@@ -67,21 +91,24 @@ def disable() -> None:
 
 
 class override:
-    """Scoped enable/disable (tests)."""
+    """Scoped enable/disable (tests); ``fence`` as in :func:`enable`."""
 
-    def __init__(self, on: bool):
+    def __init__(self, on: bool, fence: Optional[bool] = None):
         self.on = bool(on)
-        self._prev: Optional[bool] = None
+        self.fence = fence
+        self._prev = None
 
     def __enter__(self):
-        global _enabled
-        self._prev = _enabled
+        global _enabled, _fence
+        self._prev = (_enabled, _fence)
         _enabled = self.on
+        if self.fence is not None:
+            _fence = bool(self.fence)
         return self
 
     def __exit__(self, *exc):
-        global _enabled
-        _enabled = self._prev
+        global _enabled, _fence
+        _enabled, _fence = self._prev
         return False
 
 
@@ -103,26 +130,36 @@ def _is_traced(x) -> bool:
 
 
 def fence(x):
-    """``jax.block_until_ready(x)`` when obs is enabled; identity (and in
-    particular no device sync) when disabled or ``x`` contains tracers."""
-    if _enabled and not _is_traced(x):
+    """``jax.block_until_ready(x)`` when obs is enabled with fencing on;
+    identity (and in particular no device sync) when disabled, unfenced or
+    ``x`` contains tracers."""
+    if _enabled and _fence and not _is_traced(x):
         return _block(x)
     return x
+
+
+def wait(x):
+    """Block until ``x`` is ready whatever the switches say: for observers
+    that run beside the instrumented code (the serving completion
+    watcher), never for the code itself."""
+    return _block(x)
 
 
 class Span:
     """One timed stage entry (enabled path — see :func:`span`)."""
 
-    __slots__ = ("name", "_t0", "_annotation")
+    __slots__ = ("name", "_meta", "_t0", "_annotation")
 
-    def __init__(self, name: str):
+    def __init__(self, name: str, meta: dict):
         self.name = name
+        self._meta = meta
         self._t0 = 0.0
         self._annotation = None
 
     def __enter__(self):
         _stack().append(self.name)
-        self._annotation = jax.profiler.TraceAnnotation(self.name)
+        self._annotation = jax.profiler.TraceAnnotation(self.name,
+                                                        **self._meta)
         self._annotation.__enter__()
         self._t0 = time.perf_counter()
         return self
@@ -139,8 +176,13 @@ class Span:
 
     def fence(self, x):
         """Block on ``x`` so its device work lands in this span (no-op on
-        tracers); returns ``x`` for inline use."""
+        tracers and with fencing off); returns ``x`` for inline use."""
         return fence(x)
+
+    def annotate(self, **meta) -> None:
+        """Add keyword arguments known only inside the span to its trace
+        annotation (the profiler keeps them as the event's arguments)."""
+        self._annotation.set_metadata(**meta)
 
 
 class _NullSpan:
@@ -158,12 +200,17 @@ class _NullSpan:
     def fence(x):
         return x
 
+    @staticmethod
+    def annotate(**meta) -> None:
+        pass
+
 
 _NULL_SPAN = _NullSpan()
 
 
-def span(name: str):
-    """Context manager timing stage ``name`` (module docstring)."""
+def span(name: str, **meta):
+    """Context manager timing stage ``name`` (module docstring); ``meta``
+    becomes the keyword arguments of its trace annotation."""
     if not _enabled:
         return _NULL_SPAN
-    return Span(name)
+    return Span(name, meta)

@@ -65,9 +65,21 @@ class _Op(NamedTuple):
     kind: str            # "insert" | "delete" | "flush" | "compact" | "barrier"
     payload: tuple
     future: Future
+    t_admit: Optional[float] = None   # perf_counter at admission (obs on,
+                                      # writes and maintenance only)
 
 
 _STOP = object()
+
+
+def _record_since_admit(name: str, ops) -> None:
+    """Time from each op's admission to now, for ops admitted with obs on."""
+    admitted = [op.t_admit for op in ops if op.t_admit is not None]
+    if admitted:
+        now = time.perf_counter()
+        h = obs.histogram(name, persistent=True)
+        for t in admitted:
+            h.record(now - t)
 
 
 class IndexServer:
@@ -200,7 +212,8 @@ class IndexServer:
         if not self._started or self._stopped:
             raise RuntimeError("server is not running")
         fut: Future = Future()
-        op = _Op(kind, payload, fut)
+        timed = obs.enabled() and kind != "barrier"
+        op = _Op(kind, payload, fut, time.perf_counter() if timed else None)
         sheddable = (kind == "insert" if self.cfg.shed_policy ==
                      "shed_inserts" else
                      kind in ("insert", "delete")
@@ -238,6 +251,7 @@ class IndexServer:
                     break
                 ops.append(nxt)
             if ops:
+                _record_since_admit("serving_write_wait_seconds", ops)
                 self._apply(ops)
             if stop:
                 return
@@ -262,6 +276,7 @@ class IndexServer:
                 except BaseException as e:   # noqa: BLE001 - forwarded
                     outcomes.append((op, False, e))
         version = self._publish()
+        _record_since_admit("serving_write_visible_seconds", ops)
         # futures resolve only after the publish: a completed write is a
         # *visible* write
         for op, ok, val in outcomes:

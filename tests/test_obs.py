@@ -195,6 +195,46 @@ class TestSpans:
         assert obs.ENV_VAR == "REPRO_OBS"
         assert not obs.enabled()              # suite runs with obs off
 
+    def test_unfenced_keeps_spans_and_drops_fences(self, monkeypatch):
+        calls = []
+        monkeypatch.setattr("repro.obs.spans._block",
+                            lambda x: calls.append(1) or x)
+        x = jax.numpy.ones(3)
+        before = obs.histogram("stage_seconds", persistent=True,
+                               stage="unfenced").count
+        with obs.override(True, fence=False):
+            assert obs.enabled() and not obs.fencing()
+            with obs.span("unfenced") as sp:
+                assert sp.fence(x) is x
+            assert obs.fence(x) is x
+            obs.wait(x)                       # observers still may wait
+        assert calls == [1]
+        assert obs.histogram("stage_seconds", persistent=True,
+                             stage="unfenced").count == before + 1
+
+    @pytest.mark.parametrize("value,fenced", [("0", False), ("false", False),
+                                              ("1", True), (None, True)])
+    def test_fence_env_var(self, monkeypatch, value, fenced):
+        assert obs.FENCE_ENV_VAR == "REPRO_OBS_FENCE"
+        if value is None:
+            monkeypatch.delenv(obs.FENCE_ENV_VAR, raising=False)
+        else:
+            monkeypatch.setenv(obs.FENCE_ENV_VAR, value)
+        with obs.override(False):             # restores both switches
+            obs.enable()
+            assert obs.fencing() is fenced
+            obs.enable(fence=not fenced)      # the argument wins
+            assert obs.fencing() is (not fenced)
+        assert not obs.fencing()              # disabled never fences
+
+    def test_span_annotation_arguments(self):
+        with obs.span("noop", a=1) as sp:     # disabled: shared no-op
+            sp.annotate(b=2)
+        with obs.override(True):
+            with obs.span("annotated", batch_id=3) as sp:
+                sp.annotate(version=7)
+                assert obs.current_spans() == ("annotated",)
+
 
 # ---------------------------------------------------------------------------
 # zero-overhead contract: search results bit-identical with obs on/off
@@ -233,6 +273,27 @@ class TestBitIdentical:
         assert np.asarray(d_off).tobytes() == np.asarray(d_on).tobytes()
         assert np.array_equal(np.asarray(i_off), np.asarray(i_on))
 
+    def test_search_identical_unfenced(self, backend):
+        with use_backend(backend):
+            idx = _small_index()
+            Q = random_walks(5, 32, seed=9)
+            d_off, i_off = idx.search(Q, n_probe=2, topk=3)
+            with obs.override(True, fence=False):
+                d_on, i_on = idx.search(Q, n_probe=2, topk=3)
+        assert np.asarray(d_off).tobytes() == np.asarray(d_on).tobytes()
+        assert np.array_equal(np.asarray(i_off), np.asarray(i_on))
+
+    def test_unfenced_search_never_fences(self, backend, monkeypatch):
+        def forbid(x):
+            raise AssertionError("unfenced search must not block_until_ready"
+                                 " through the obs layer")
+        with use_backend(backend):
+            idx = _small_index()
+            monkeypatch.setattr("repro.obs.spans._block", forbid)
+            with obs.override(True, fence=False):
+                idx.search(random_walks(3, 32, seed=9), n_probe=2, topk=3)
+                idx.flush()
+
     def test_disabled_search_never_fences(self, backend, monkeypatch):
         def forbid(x):
             raise AssertionError("obs-off search must not block_until_ready"
@@ -241,6 +302,20 @@ class TestBitIdentical:
         with use_backend(backend):
             idx = _small_index()
             idx.search(random_walks(3, 32, seed=9), n_probe=2, topk=3)
+
+
+def test_search_stages_name_their_device_work():
+    """The jitted stage bodies carry ``index.search.<stage>`` scopes, so a
+    profile can split device time by stage without fences."""
+    from repro.index.streaming import search_impl
+    idx = _small_index()
+    Q = jax.numpy.asarray(random_walks(2, 32, seed=9))
+    hlo = jax.jit(lambda q: search_impl(
+        idx.coarse, idx.cb, tuple(idx.segments), idx._hot_arrays(), q,
+        icfg=idx.cfg, n_probe=2, topk=3, dim=idx.dim)).lower(Q).as_text(
+            debug_info=True)
+    for stage in ("lut", "fine", "hot", "merge"):
+        assert f"index.search.{stage}" in hlo, stage
 
 
 class TestFilteredTopkStats:

@@ -21,6 +21,8 @@ from repro.data.timeseries import cbf
 from repro.index import IndexConfig, StreamingIndex
 from repro.serve_index import (SHED_POLICIES, Backpressure, IndexServer,
                                ServeConfig)
+from repro.serve_index.coalescer import _DEPTH_BUCKETS, QueryCoalescer
+from repro.serve_index.server import SearchResult
 
 
 def _config(n_lists=4, hot_capacity=12):
@@ -362,3 +364,265 @@ class TestServingObs:
                   if h["name"] == "stage_seconds"}
         assert {"serving.apply", "serving.snapshot_swap",
                 "serving.batch_search"} <= stages
+
+
+# ---------------------------------------------------------------------------
+# request-scoped tracing: ids, phase times, the completion watcher and the
+# fence switch
+# ---------------------------------------------------------------------------
+
+def _samples(name, **labels):
+    return list(obs.histogram(name, persistent=True, **labels).samples)
+
+
+def _new(name, before):
+    return _samples(name)[len(before):]
+
+
+def _obs_free_snapshot():
+    """Every metric except the dispatch routing mirror, which counts eager
+    dispatches whether obs is on or off."""
+    from repro.obs import export
+    snap = export.snapshot()
+    return {k: [m for m in snap[k] if m["name"] != "dispatch_total"]
+            for k in ("counters", "gauges", "histograms")}
+
+
+def _thread_names():
+    return {t.name for t in threading.enumerate()}
+
+
+def _serve(idx, queries, cfg):
+    with IndexServer(idx, cfg) as srv:
+        return [srv.submit_search(q).result(timeout=120) for q in queries]
+
+
+class _SlowBatches:
+    """A ``run_batch`` stand-in that spends ``seconds`` in the call."""
+
+    def __init__(self, seconds):
+        self.seconds = seconds
+
+    def __call__(self, Qp, q_valid, n_real):
+        time.sleep(self.seconds)
+        return SearchResult(jnp.zeros((Qp.shape[0], 1)),
+                            jnp.zeros((Qp.shape[0], 1), jnp.int32), 0)
+
+
+def _coalesced(run_batch, n, window=0.0):
+    """Submit ``n`` one-row requests one after the other through a bare
+    coalescer; returns the results."""
+    co = QueryCoalescer(run_batch, ServeConfig(coalesce_window_s=window))
+    co.start()
+    try:
+        return [co.submit(np.zeros((1, 4), np.float32)).result(timeout=60)
+                for _ in range(n)]
+    finally:
+        co.stop()
+
+
+class TestRequestTracing:
+    def test_obs_off_serving_starts_no_watcher_and_records_nothing(
+            self, data, booted, monkeypatch):
+        X, Q = data
+        idx = _fresh(booted)
+        idx.insert(X[:16])
+        cfg = ServeConfig(n_probe=2, topk=1, coalesce_window_s=0.0)
+        _serve(idx, [Q[:2]], cfg)                 # compile outside the check
+
+        def forbid(x):
+            raise AssertionError("obs-off serving must not sync through obs")
+        monkeypatch.setattr("repro.obs.spans._block", forbid)
+        assert not obs.enabled()
+        before = _obs_free_snapshot()
+        seen = set()
+        with IndexServer(idx, cfg) as srv:
+            for i in range(3):
+                srv.submit_search(Q[i:i + 2]).result(timeout=120)
+                seen |= _thread_names()
+        assert "repro-serve-watcher" not in seen
+        assert _obs_free_snapshot() == before
+
+    @pytest.mark.parametrize("backend", ["jax", "pallas_interpret"])
+    def test_unfenced_coalescer_never_blocks_and_matches_obs_off(
+            self, data, booted, backend, monkeypatch):
+        X, Q = data
+        idx = _fresh(booted)
+        idx.insert(X[:20])
+        idx.flush()                               # sealed rows + hot rows
+        cfg = ServeConfig(n_probe=2, topk=2, coalesce_window_s=0.0)
+        queries = [Q[:1], Q[1:4], Q[2:6]]
+        callers = []
+
+        def record(x):
+            callers.append(threading.current_thread().name)
+            return jax.block_until_ready(x)
+        with use_backend(backend):
+            off = _serve(idx, queries, cfg)
+            monkeypatch.setattr("repro.obs.spans._block", record)
+            with obs.override(True, fence=False):
+                on = _serve(idx, queries, cfg)
+        assert "repro-serve-coalescer" not in callers
+        assert "repro-serve-watcher" in callers   # the watcher did wait
+        for a, b in zip(off, on):
+            assert np.asarray(a.dist).tobytes() == np.asarray(b.dist).tobytes()
+            assert np.array_equal(np.asarray(a.ids), np.asarray(b.ids))
+
+    @pytest.mark.parametrize("fence", [True, False])
+    def test_wait_excludes_and_request_includes_the_search(self, fence):
+        """The coalesce wait ends when the batch is handed to ``run_batch``;
+        the request time runs until the answer is ready, so a 50 ms search
+        lies inside every request time and outside every wait."""
+        waits = _samples("serving_coalesce_wait_seconds")
+        reqs = _samples("serving_request_seconds")
+        with obs.override(True, fence=fence):
+            _coalesced(_SlowBatches(0.05), 4)
+        new_w = _new("serving_coalesce_wait_seconds", waits)
+        new_r = _new("serving_request_seconds", reqs)
+        assert len(new_w) == len(new_r) == 4
+        assert all(w < 0.05 for w in new_w)
+        assert all(r >= 0.05 for r in new_r)
+        assert all(r >= w for w, r in zip(new_w, new_r))
+
+    def test_dispatch_and_inflight_split_the_batch(self):
+        """Unfenced, the host time inside ``run_batch`` is the dispatch and
+        the rest until ready is in flight; each batch gives one sample of
+        each, and the in-flight depth is sampled at each dispatch."""
+        names = ("serving_batch_dispatch_seconds",
+                 "serving_batch_inflight_seconds")
+        before = {n: _samples(n) for n in names}
+        h = obs.histogram("serving_inflight_depth", persistent=True,
+                          buckets=_DEPTH_BUCKETS)
+        depth = len(h.samples)
+        with obs.override(True, fence=False):
+            _coalesced(_SlowBatches(0.02), 3)
+        dispatch = _new(names[0], before[names[0]])
+        inflight = _new(names[1], before[names[1]])
+        assert len(dispatch) == len(inflight) == 3
+        assert all(d >= 0.02 for d in dispatch)
+        assert all(t >= 0.0 for t in inflight)
+        assert len(h.samples) == depth + 3
+        assert all(s >= 1 for s in h.samples[depth:])
+        assert obs.gauge("serving_batches_in_flight",
+                         persistent=True).value == 0
+
+    def test_n_requests_n_samples_and_busy_idle_grow(self, data, booted):
+        X, Q = data
+        idx = _fresh(booted)
+        idx.insert(X[:16])
+        names = ("serving_coalesce_wait_seconds", "serving_request_seconds")
+        before = {n: _samples(n) for n in names}
+        busy = obs.counter("serving_coalescer_busy_seconds",
+                           persistent=True).value
+        idle = obs.counter("serving_coalescer_idle_seconds",
+                           persistent=True).value
+        with obs.override(True, fence=False):
+            _serve(idx, [Q[i:i + 1] for i in range(5)],
+                   ServeConfig(n_probe=2, topk=1, coalesce_window_s=0.0))
+        for n in names:
+            assert len(_new(n, before[n])) == 5
+        assert obs.counter("serving_coalescer_busy_seconds",
+                           persistent=True).value > busy
+        assert obs.counter("serving_coalescer_idle_seconds",
+                           persistent=True).value > idle
+
+    def test_concurrent_clients_lose_no_sample(self):
+        """More client threads than cores and a short switch interval: every
+        request still gives one wait and one request sample, the batches
+        account for every row, and the watcher drains to 0 in flight."""
+        import os
+        import sys
+        n_threads = (os.cpu_count() or 2) + 2
+        per_thread = max(2, 128 // n_threads)
+        names = ("serving_coalesce_wait_seconds", "serving_request_seconds")
+        before = {n: _samples(n) for n in names}
+        rows = obs.histogram("serving_batch_queries", persistent=True,
+                             q_buckets="1,2,4,8,16,32,64",
+                             buckets=(1.0, 2.0, 4.0, 8.0, 16.0, 32.0, 64.0))
+        rows_before = rows.sum
+        co = QueryCoalescer(_SlowBatches(0.001),
+                            ServeConfig(coalesce_window_s=0.0005))
+        errors = []
+
+        def client():
+            try:
+                for _ in range(per_thread):
+                    co.submit(np.zeros((1, 4), np.float32)).result(
+                        timeout=60)
+            except Exception as e:            # noqa: BLE001 - reported
+                errors.append(e)
+
+        old = sys.getswitchinterval()
+        sys.setswitchinterval(1e-6)
+        try:
+            with obs.override(True, fence=False):
+                co.start()
+                threads = [threading.Thread(target=client)
+                           for _ in range(n_threads)]
+                for t in threads:
+                    t.start()
+                for t in threads:
+                    t.join(timeout=120)
+                co.stop()
+        finally:
+            sys.setswitchinterval(old)
+        assert not errors and not any(t.is_alive() for t in threads)
+        n = n_threads * per_thread
+        for name in names:
+            assert len(_new(name, before[name])) == n, name
+        assert rows.sum - rows_before == n
+        assert obs.gauge("serving_batches_in_flight",
+                         persistent=True).value == 0
+
+    def test_coalescer_phases_are_spans(self, obs_on):
+        counts = {s: len(_samples("stage_seconds", stage=s))
+                  for s in ("serving.coalesce", "serving.batch_search",
+                            "serving.deliver")}
+        _coalesced(_SlowBatches(0.0), 2)
+        for s, n in counts.items():
+            assert len(_samples("stage_seconds", stage=s)) >= n + 2, s
+
+    def test_batch_annotation_carries_ids(self, tmp_path):
+        """The profiler keeps the launch annotation's name and its keyword
+        arguments apart, so trace stages still match by name."""
+        import glob
+        from jax.profiler import ProfileData
+        with obs.override(True, fence=False):
+            jax.profiler.start_trace(str(tmp_path))
+            try:
+                _coalesced(_SlowBatches(0.0), 2)
+            finally:
+                jax.profiler.stop_trace()
+        (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                            recursive=True)
+        events = [e for plane in ProfileData.from_file(path).planes
+                  for line in plane.lines for e in line.events
+                  if e.name == "serving.batch_search"]
+        assert len(events) == 2
+        args = [{k: v for k, v in e.stats} for e in events]
+        assert {"batch_id", "n_real", "bucket", "version", "first_request",
+                "oldest_wait_ms"} <= set(args[0])
+        assert args[1]["batch_id"] == args[0]["batch_id"] + 1
+        assert args[1]["first_request"] == args[0]["first_request"] + 1
+        assert [a["n_real"] for a in args] == [1, 1]
+
+    def test_write_wait_visible_and_flush_subspans(self, data, booted,
+                                                   obs_on):
+        X, _ = data
+        idx = _fresh(booted)
+        names = ("serving_write_wait_seconds", "serving_write_visible_seconds")
+        before = {n: _samples(n) for n in names}
+        subs = {s: len(_samples("stage_seconds", stage=s))
+                for s in ("index.flush.encode", "index.flush.assign",
+                          "index.flush.seal")}
+        with IndexServer(idx, ServeConfig(n_probe=2, topk=1)) as srv:
+            futs = [srv.insert(X[:8]), srv.insert(X[8:16]), srv.flush()]
+            for f in futs:
+                f.result(timeout=120)
+            srv.quiesce(timeout=120)              # a barrier is not a write
+        wait = _new(names[0], before[names[0]])
+        visible = _new(names[1], before[names[1]])
+        assert len(wait) == len(visible) == 3
+        assert all(v >= w >= 0 for w, v in zip(wait, visible))
+        for s, n in subs.items():
+            assert len(_samples("stage_seconds", stage=s)) > n, s
