@@ -1,7 +1,8 @@
 """DBA k-means — the codebook learner of the paper's training phase.
 
-Assignment uses the batched wavefront through the elastic dispatch layer
-(`dispatch.elastic_cdist` — Pallas kernel on TPU) under any registered
+Assignment is one all-pairs launch through the elastic dispatch layer
+(`dispatch.elastic_cdist`; on TPU the Pallas kernel that sweeps a few
+series at once against 128 centroids on lanes) under any registered
 elastic measure; the update step runs one
 or more DBA iterations per round, where each series contributes only to its
 assigned centroid (scatter-add by cluster id, so the cost per round is N

@@ -378,9 +378,10 @@ def query_lut_batch(q_segs: jnp.ndarray, cb: PQCodebook,
                     measure: Optional[MeasureSpec] = None) -> jnp.ndarray:
     """Batched asymmetric tables: ``q_segs (Nq, M, S)`` -> ``(Nq, M, K)``.
 
-    One all-pairs dispatch launch per subspace; the cdist kernel broadcasts
-    each centroid row per tile, so the Nq x K cross-product of series is
-    never materialized.
+    One all-pairs dispatch launch per subspace; on the compiled route the
+    kernel sweeps a few query segments at once against 128 codewords on
+    lanes, its register only the band's slots deep, so the Nq x K
+    cross-product of series is never materialized.
 
     >>> import jax, jax.numpy as jnp
     >>> cfg = PQConfig(n_sub=2, codebook_size=2, use_prealign=False,

@@ -41,6 +41,12 @@ TPU notes (both kernels):
   * the band geometry is integer arithmetic on the loop counter, so shapes
     never depend on data.
 
+The all-pairs kernel (``dtw_band_cdist_kernel``) turns the compressed
+register on its side: band slots on sublanes, one B row per lane, a few A
+rows stacked as independent chains.  Its windows are dynamic sublane
+slices of time-major VMEM buffers and its predecessor shifts sublane
+rotates, so a launch's work follows its real rows and band cells.
+
 Measure-generic: the band-compressed sweep takes a static
 :class:`repro.core.measures.MeasureSpec` whose per-move costs are inlined
 into the wavefront step, so one kernel body serves DTW, WDTW, ERP and MSM
@@ -77,6 +83,9 @@ __all__ = [
 
 _NEG_SAFE_INF = 3.0e38  # finite stand-in for +inf (avoids inf-inf NaNs)
 _LANES = 128            # TPU vreg lane count: rotates run on whole lane tiles
+_SUBLANES = 8           # TPU vreg sublane count
+# VMEM the all-pairs kernel may spend on A rows broadcast across the lanes
+_A_LANES_BYTES = 4 * 1024 * 1024
 
 
 def _pad_lanes(x: jnp.ndarray, left: int = 0,
@@ -353,28 +362,128 @@ def dtw_band_compressed_kernel(a_ref, b_rev_ref, o_ref, *, length: int,
                                       width=width, measure=measure)
 
 
-def dtw_band_cdist_kernel(a_ref, b_rev_ref, o_ref, *, length: int,
-                          window: int, block_a: int, block_b: int,
-                          width: int, measure: MeasureArg = None):
-    """All-pairs tile: ``a_ref (block_a, L)`` x ``b_rev_ref (block_b, L)``
-    (time reversed) -> ``o_ref (block_a, block_b)``.
+def _cdist_streams(spec, x: jnp.ndarray, reversed_: bool) -> jnp.ndarray:
+    """The series one side of the all-pairs sweep reads per diagonal,
+    ``(n_streams, rows, L)``: the values, then their predecessors
+    (``uses_neighbors``), then the virtual border prefix sums and their
+    predecessors (``uses_gap_border``), each built exactly as
+    :func:`wavefront_compressed` builds it.  ``reversed_`` marks the B side,
+    passed time reversed."""
+    rows, L = x.shape
+    streams = [x]
+    if spec.uses_neighbors:
+        streams.append(
+            jnp.concatenate([x[:, 1:], x[:, -1:]], axis=1) if reversed_
+            else jnp.concatenate([x[:, :1], x[:, :-1]], axis=1))
+    if spec.uses_gap_border:
+        g = _prefix_sum(measures.gap_costs(spec, x), L, reverse=reversed_)
+        zero = jnp.zeros((rows, 1), jnp.float32)
+        streams += [g, jnp.concatenate([g[:, 1:], zero], axis=1) if reversed_
+                    else jnp.concatenate([zero, g[:, :-1]], axis=1)]
+    return jnp.stack(streams)
 
-    One band-compressed sweep per B row, broadcast against the A tile; each
-    sweep's ``(block_a, 1)`` column is selected into the lane-dense output
-    tile, which is stored once.
+
+def dtw_band_cdist_kernel(a_ref, b_ref, o_ref, a_lanes, *, length: int,
+                          window: int, width: int,
+                          measure: MeasureArg = None):
+    """All-pairs tile: ``chains`` rows of A against ``n_b`` (a lane tile)
+    rows of B, one anti-diagonal sweep for all of them.
+
+    ``a_ref (1, n_streams, L_pad, chains)`` holds the A rows' streams (see
+    :func:`_cdist_streams`) time major; ``b_ref (n_streams, L_pad, n_b)``
+    the B rows' streams, time reversed and time major, one B row per lane;
+    ``o_ref (1, chains, n_b)``; ``a_lanes (n_streams, chains, L_pad, n_b)``
+    is VMEM scratch that holds each A row broadcast across the lanes.
+
+    Band slots sit on sublanes and B rows on lanes: the register is
+    ``(chains * width, n_b)``, ``width`` slots per A row stacked row above
+    row, so the A rows run as independent chains through one sweep.  Slot
+    ``t`` of diagonal ``d`` is cell ``i = lo(d) + t`` (module header); its
+    values are the dynamic sublane windows ``a[lo + t]`` and ``b_rev[L-1-d
+    + lo + t]`` of the time-major buffers, and the predecessor shifts are
+    sublane rotates whose wrap into the next row's slots is masked off.
+    The per-cell recurrence is :func:`wavefront_compressed`'s.
     """
-    a = a_ref[...].astype(jnp.float32)
-    col = jax.lax.broadcasted_iota(jnp.int32, (block_a, block_b), 1)
+    spec = measures.resolve(measure)
+    L, w, W = length, window, width
+    chains = a_ref.shape[3]
+    n_b = b_ref.shape[2]
+    rows = chains * W
+    for k in range(a_ref.shape[1]):
+        for c in range(chains):
+            a_lanes[k, c] = jnp.broadcast_to(
+                a_ref[0, k, :, c:c + 1], a_lanes.shape[2:])
 
-    def one_row(j, out):
-        b_rev = jnp.broadcast_to(
-            b_rev_ref[pl.ds(j, 1), :].astype(jnp.float32), (block_a, length))
-        d = wavefront_compressed(a, b_rev, length=length, window=window,
-                                 width=width, measure=measure)
-        return jnp.where(col == j, d, out)
+    inf = jnp.float32(_NEG_SAFE_INF)
+    t = jnp.concatenate(
+        [jax.lax.broadcasted_iota(jnp.int32, (W, n_b), 0)] * chains, axis=0)
 
-    o_ref[...] = jax.lax.fori_loop(0, block_b, one_row,
-                                   carry_full((block_a, block_b), 0.0))
+    def a_win(k, lo):
+        return jnp.concatenate([a_lanes[k, c, pl.ds(lo, W), :]
+                                for c in range(chains)], axis=0)
+
+    def b_win(k, off):
+        return jnp.concatenate([b_ref[k, pl.ds(off, W), :]] * chains, axis=0)
+
+    def lo_of(d):
+        # max(0, d - (L-1), ceil((d - w) / 2)); jnp // is floor division.
+        return jnp.maximum(jnp.maximum(0, d - (L - 1)), -((w - d) // 2))
+
+    def read(reg, s):
+        """``reg[t + s]`` for scalar shift ``s`` in {-1, 0, 1}; slots out of
+        the row's band read the +inf sentinel (sublane rotate + mask)."""
+        left = jnp.where(t == W - 1, inf, pltpu.roll(reg, rows - 1, 0))
+        right = jnp.where(t == 0, inf, pltpu.roll(reg, 1, 0))
+        return jnp.where(s == 0, reg, jnp.where(s > 0, left, right))
+
+    gap = 1 + spec.uses_neighbors     # stream index of the border sums
+
+    def step(d, carry):
+        prev1, prev2 = carry  # compressed diagonals d-1 / d-2, inf-masked
+        lo = lo_of(d)
+        hi = jnp.minimum(jnp.minimum(L - 1, d), (d + w) // 2)
+        s1 = lo - lo_of(d - 1)
+        s2 = lo - lo_of(d - 2) - 1
+        off_b = L - 1 - d + lo
+
+        av = a_win(0, lo)
+        bv = b_win(0, off_b)
+        i_arr = lo + t
+        xp = a_win(1, lo) if spec.uses_neighbors else None
+        yp = b_win(1, off_b) if spec.uses_neighbors else None
+        dd = jnp.abs(2 * i_arr - d) if spec.uses_position else None
+        c_d, c_v, c_h = measures.move_costs(spec, av, bv, xp, yp, dd, L)
+
+        pred_h = read(prev1, s1)
+        pred_v = read(prev1, s1 - 1)
+        pred_d = read(prev2, s2)
+        is_i0 = i_arr == 0
+        is_j0 = (d - i_arr) == 0
+        if spec.uses_gap_border:
+            ga_v = a_win(gap, lo)
+            gap_v = a_win(gap + 1, lo)
+            gb_v = b_win(gap, off_b)
+            gbp_v = b_win(gap + 1, off_b)
+            pred_d = jnp.where(is_i0, gbp_v, jnp.where(is_j0, gap_v, pred_d))
+            pred_d = jnp.where(is_i0 & is_j0, 0.0, pred_d)
+            pred_v = jnp.where(is_i0, gb_v, pred_v)
+            pred_h = jnp.where(is_j0, ga_v, pred_h)
+        else:
+            pred_d = jnp.where(is_i0 & is_j0, 0.0, pred_d)
+        if c_v is c_d and c_h is c_d:   # shared-cost family (DTW, WDTW)
+            cell = c_d + jnp.minimum(jnp.minimum(pred_d, pred_h), pred_v)
+        else:
+            cell = jnp.minimum(jnp.minimum(pred_d + c_d, pred_v + c_v),
+                               pred_h + c_h)
+        diag = jnp.where(t <= hi - lo, cell, inf)
+        diag = jnp.minimum(diag, inf)
+        return diag, prev1
+
+    init = carry_full((rows, n_b), _NEG_SAFE_INF)
+    last, _ = jax.lax.fori_loop(0, 2 * L - 1, step, (init, init))
+    # Diagonal 2L-2 has lo = L-1: cell (L-1, L-1) sits in each row's slot 0.
+    for c in range(chains):
+        o_ref[0, c:c + 1, :] = last[c * W:c * W + 1, :]
 
 
 def dtw_band_adaptive_kernel(a_ref, b_rev_ref, lo_ref, hi_ref, o_ref, *,
@@ -463,34 +572,56 @@ def make_dtw_band_call(n_pairs: int, length: int, window: Optional[int],
 
 
 def make_dtw_band_cdist_call(n_a: int, n_b: int, length: int,
-                             window: Optional[int], block_a: int,
-                             interpret: bool, lane: int = 8,
-                             measure: MeasureArg = None):
-    """All-pairs call on a 2-D grid: ``A (n_a, L) x B_rev (n_b, L) -> (n_a,
-    n_b)`` with B passed time reversed.
+                             window: Optional[int], block: int,
+                             interpret: bool, measure: MeasureArg = None):
+    """All-pairs call: ``A (n_a, L) x B_rev (n_b, L) -> (n_a, n_b)``, B
+    passed time reversed; the returned function lays both sides out time
+    major and runs :func:`dtw_band_cdist_kernel` on a 2-D grid.
 
-    Each grid step sweeps ``block_a`` rows of A against ``block_b =
-    min(n_b, 128)`` rows of B, one B row at a time broadcast inside the
-    kernel, so the N*M cross-product is never materialized in HBM and the
-    output tile is lane dense.  ``n_a`` must be padded to a multiple of
-    ``block_a`` and ``n_b`` to a multiple of ``block_b``.
+    The band register is ``width`` sublanes per A row (the band's cells
+    rounded up to 8) by 128 B rows on lanes.  A grid step runs ``chains``
+    A rows as independent chains: as many as fit ``block`` register
+    sublanes, never more than ``n_a`` and never more than the VMEM that
+    broadcasting them across the lanes may take.  So a launch's work
+    scales with its real rows and band cells; only B is padded, to whole
+    lane tiles, and the ``n_a x n_b`` cross-product never reaches HBM.
     """
+    spec = measures.resolve(measure)
     w = effective_window(length, window)
-    block_b = min(n_b, _LANES)
-    if n_b % block_b:
-        raise ValueError(f"n_b={n_b} must be a multiple of {block_b}")
+    width = cdiv(min(w, length - 1) + 1, _SUBLANES) * _SUBLANES
+    l_pad = cdiv(length + width - 1, _SUBLANES) * _SUBLANES
+    n_streams = 1 + spec.uses_neighbors + 2 * spec.uses_gap_border
+    chains = max(1, min(n_a, block // width,
+                        _A_LANES_BYTES // (n_streams * l_pad * _LANES * 4)))
+    n_blocks = cdiv(n_a, chains)
+    b_tiles = cdiv(n_b, _LANES)
     kernel = functools.partial(dtw_band_cdist_kernel, length=length,
-                               window=w, block_a=block_a, block_b=block_b,
-                               width=band_width(length, w, lane),
-                               measure=measures.resolve(measure))
-    return pl.pallas_call(
+                               window=w, width=width, measure=spec)
+    call = pl.pallas_call(
         kernel,
-        grid=(n_a // block_a, n_b // block_b),
+        grid=(n_blocks, b_tiles),
         in_specs=[
-            pl.BlockSpec((block_a, length), lambda i, j: (i, 0)),
-            pl.BlockSpec((block_b, length), lambda i, j: (j, 0)),
+            pl.BlockSpec((1, n_streams, l_pad, chains),
+                         lambda i, j: (i, 0, 0, 0)),
+            pl.BlockSpec((n_streams, l_pad, _LANES), lambda i, j: (0, 0, j)),
         ],
-        out_specs=pl.BlockSpec((block_a, block_b), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((n_a, n_b), jnp.float32),
+        out_specs=pl.BlockSpec((1, chains, _LANES), lambda i, j: (i, 0, j)),
+        out_shape=jax.ShapeDtypeStruct((n_blocks, chains, b_tiles * _LANES),
+                                       jnp.float32),
+        scratch_shapes=[pltpu.VMEM((n_streams, chains, l_pad, _LANES),
+                                   jnp.float32)],
         interpret=interpret,
     )
+
+    def run(A: jnp.ndarray, B_rev: jnp.ndarray) -> jnp.ndarray:
+        a = _cdist_streams(spec, A, reversed_=False)
+        a = jnp.pad(a, ((0, 0), (0, n_blocks * chains - n_a),
+                        (0, l_pad - length)))
+        a = a.reshape(n_streams, n_blocks, chains, l_pad).transpose(1, 0, 3, 2)
+        b = _cdist_streams(spec, B_rev, reversed_=True)
+        b = jnp.pad(b, ((0, 0), (0, b_tiles * _LANES - n_b),
+                        (0, l_pad - length))).transpose(0, 2, 1)
+        out = call(a, b).reshape(n_blocks * chains, b_tiles * _LANES)
+        return out[:n_a, :n_b]
+
+    return run

@@ -29,14 +29,14 @@ def _backend_name(interpret: bool) -> str:
 
 def _tuned_block(op: str, block: Optional[int], *, length: int,
                  window: Optional[int], measure: MeasureArg,
-                 interpret: bool, param: str = "block") -> int:
+                 interpret: bool) -> int:
     """``block=None`` consults the tuning table (a trace-time Python
     resolution — the result is a static launch parameter), falling back
     to the backend's builtin block; an explicit block always wins."""
     if block is not None:
         return block
     backend = _backend_name(interpret)
-    return tune.tuned(op, param, length=length, window=window,
+    return tune.tuned(op, "block", length=length, window=window,
                       measure=measures.resolve(measure).name,
                       backend=backend,
                       default=tune.default_block(op, backend))
@@ -96,38 +96,28 @@ def dtw_band(A: jnp.ndarray, B: jnp.ndarray, window: Optional[int] = None,
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("window", "block", "interpret", "lane",
-                                    "measure"))
+                   static_argnames=("window", "block", "interpret", "measure"))
 def dtw_band_cdist(A: jnp.ndarray, B: jnp.ndarray,
                    window: Optional[int] = None, block: Optional[int] = None,
                    interpret: Optional[bool] = None,
-                   lane: Optional[int] = None,
                    measure: MeasureArg = None) -> jnp.ndarray:
     """All-pairs banded elastic cost: ``A (N, L)``, ``B (M, L)`` -> ``(N, M)``.
 
-    Runs the band-compressed kernel on a 2-D grid (A row-blocks x B
-    row-blocks of up to 128); the N*M cross-product is never materialized —
-    B rows are broadcast inside the kernel tile.  ``block=None`` consults
-    the tuning table (``block_a``).
+    One anti-diagonal sweep per grid step covers a few A rows against 128
+    B rows: band slots on sublanes, B rows on lanes, the A rows as
+    independent register chains (see :func:`make_dtw_band_cdist_call`).
+    The N*M cross-product is never materialized.  ``block=None`` consults
+    the tuning table for the register height in sublanes, which sets how
+    many A rows share a grid step.
     """
     if interpret is None:
         interpret = default_interpret()
-    if lane is None:
-        lane = _default_lane()
     A = jnp.asarray(A, jnp.float32)
     B = jnp.asarray(B, jnp.float32)
     N, L = A.shape
     M = B.shape[0]
     block = _tuned_block("dtw_band_cdist", block, length=L, window=window,
-                         measure=measure, interpret=interpret,
-                         param="block_a")
-    Ap = pad_to(A, block, axis=0)
-    # kernels take B reversed; more than one lane tile of B rows is padded
-    # to whole tiles
-    Bp = jnp.flip(B, axis=1)
-    if M > 128:
-        Bp = pad_to(Bp, 128, axis=0)
-    call = make_dtw_band_cdist_call(Ap.shape[0], Bp.shape[0], L, window,
-                                    block, interpret, lane=lane,
+                         measure=measure, interpret=interpret)
+    call = make_dtw_band_cdist_call(N, M, L, window, block, interpret,
                                     measure=measure)
-    return call(Ap, Bp)[:N, :M]
+    return call(A, jnp.flip(B, axis=1))    # kernels take B reversed

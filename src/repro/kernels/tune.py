@@ -60,20 +60,27 @@ _TABLE_NAME = "tuning.json"
 # (row blocks are multiples of 8: a TPU tile has 8 sublanes)
 _GRIDS: Dict[str, Dict[str, Tuple[int, ...]]] = {
     "dtw_band": {"block": (8, 64, 256)},
-    "dtw_band_cdist": {"block_a": (8, 32, 128)},
+    "dtw_band_cdist": {"block": (64, 128, 256)},
     "lb_refine": {"block": (8, 64, 256)},
     "adc_sym": {"block_a": (64, 128), "block_b": (64, 128)},
     "adc_lookup": {"block": (128, 256, 512)},
 }
 
-# Builtin row blocks of the wavefront kernels on the compiled route, used
-# when no table entry applies.  One anti-diagonal step on a single
-# (8, 128) vreg is latency-bound; wider blocks run independent vreg chains
-# side by side.  Measured on a TPU v5e: dtw_band_cdist at L=256, w=25 ran
-# 125k pairs/s at block 8 and 858k pairs/s at block 128; dtw_band at
-# L=18, w=2 ran 2.1M pairs/s at block 8 and 7.3M pairs/s at block 256.
-# lb_refine skips the sweep per tile, so its block stays smaller.
-# Interpret mode keeps 8 rows.
+# Builtin blocks of the wavefront kernels on the compiled route, used when
+# no table entry applies.  One anti-diagonal step on a single (8, 128)
+# vreg is latency-bound; wider blocks run independent vreg chains side by
+# side.  dtw_band and lb_refine count rows; dtw_band_cdist counts register
+# sublanes, band slots times A rows, so the A rows it runs as chains adapt
+# to the band (4 at L=256, w=26; 16 at S=18, w=2).  Measured on a TPU v5e
+# in one run of the serving geometries (PERF.md, Findings: 64 x 256
+# lists, 16 x 64 x 256 codewords, 4096 x 256 rows): dtw_band_cdist at
+# block 128 ran 10.9M pairs/s at L=256, w=26 (bucket 64; 1.51 ms a
+# launch), 488M pairs/s at S=18, w=2 (0.54 ms for the 16 LUT launches)
+# and 11.0M pairs/s at 4096 x 256, L=256 (94.9 ms); blocks 64 and 256
+# were 1.25-1.4x slower, and 8 or 32 (one chain at L=256) 2.3x at L=256.
+# dtw_band at L=18, w=2 ran 2.1M pairs/s at block 8 and 7.3M pairs/s at
+# block 256.  lb_refine skips the sweep per tile, so its block stays
+# smaller.  Interpret mode keeps 8.
 _COMPILED_BLOCK: Dict[str, int] = {
     "dtw_band": 256,
     "dtw_band_cdist": 128,
@@ -190,12 +197,13 @@ def _measure(op: str, params: Dict[str, int], *, length: int,
                 dtw_band(A, B, window, measure=measure,
                          interpret=interpret, **params).block_until_ready()
         else:
-            blk = params.get("block_a", 8)
+            # a lane tile of B rows, as the coarse stage and LUTs launch
+            B = rng.standard_normal((128, length)).astype(np.float32)
 
             def fn():
-                dtw_band_cdist(A, B[:8], window, measure=measure,
+                dtw_band_cdist(A, B, window, measure=measure,
                                interpret=interpret,
-                               block=blk).block_until_ready()
+                               **params).block_until_ready()
         return _time_once(fn)
     if op == "lb_refine":
         from .lb_cascade.ops import lb_refine

@@ -43,12 +43,19 @@ def test_dtw_band_dtypes(dtype):
     np.testing.assert_allclose(got, want, rtol=rtol, atol=1e-2)
 
 
-def test_dtw_band_cdist_matches_ref():
-    rng = np.random.default_rng(3)
-    A = rng.standard_normal((6, 20)).astype(np.float32)
-    B = rng.standard_normal((9, 20)).astype(np.float32)
-    got = np.asarray(dtw_band_cdist(A, B, 4, interpret=True))
-    want = np.asarray(dtw_band_cdist_ref(A, B, 4))
+@pytest.mark.parametrize("L,window", [(18, 0), (18, 2), (18, None),
+                                      (64, 0), (64, 2), (64, None)])
+@pytest.mark.parametrize("m", [3, 130, 256])
+@pytest.mark.parametrize("n", [1, 3, 35, 130])
+def test_dtw_band_cdist_matches_ref(n, m, L, window):
+    """Serving-like shapes: a single query up to more than one A block,
+    fewer B rows than a lane tile up to two tiles, a zero band up to the
+    full one."""
+    rng = np.random.default_rng(n * 7 + m * 3 + L)
+    A = rng.standard_normal((n, L)).astype(np.float32)
+    B = rng.standard_normal((m, L)).astype(np.float32)
+    got = np.asarray(dtw_band_cdist(A, B, window, interpret=True))
+    want = np.asarray(dtw_band_cdist_ref(A, B, window))
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
 
 
@@ -88,26 +95,30 @@ def test_dtw_band_modes_agree():
     np.testing.assert_allclose(comp, full, rtol=1e-6, atol=1e-6)
 
 
-def test_dtw_band_cdist_no_materialize_grid():
-    """2-D grid cdist (B broadcast per tile) vs reference, odd shapes."""
+@pytest.mark.parametrize("block", [4, 24, 128])
+def test_dtw_band_cdist_no_materialize_grid(block):
+    """2-D grid cdist vs reference, odd shapes: ``block`` register
+    sublanes give one chain per grid step, a few (A rows padded to a
+    whole step), or every A row at once, depending on the band."""
     rng = np.random.default_rng(12)
     A = rng.standard_normal((11, 24)).astype(np.float32)
     B = rng.standard_normal((5, 24)).astype(np.float32)
     for window in (None, 2, 50):
-        got = np.asarray(dtw_band_cdist(A, B, window, block=4,
+        got = np.asarray(dtw_band_cdist(A, B, window, block=block,
                                         interpret=True))
         want = np.asarray(dtw_band_cdist_ref(A, B, window))
         np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
 
 
-def test_dtw_band_cdist_pads_b_to_lane_tiles():
-    """More than 128 B rows: B is padded to whole 128-row tiles and the
+@pytest.mark.parametrize("m", [3, 128, 130, 256])
+def test_dtw_band_cdist_pads_b_to_lane_tiles(m):
+    """B rows sit on lanes: they are padded to whole 128-row tiles and the
     padded output columns are sliced off."""
     rng = np.random.default_rng(13)
     A = rng.standard_normal((3, 10)).astype(np.float32)
-    B = rng.standard_normal((130, 10)).astype(np.float32)
-    got = np.asarray(dtw_band_cdist(A, B, 2, interpret=True))
-    assert got.shape == (3, 130)
+    B = rng.standard_normal((m, 10)).astype(np.float32)
+    got = np.asarray(dtw_band_cdist(A, B, 2, block=16, interpret=True))
+    assert got.shape == (3, m)
     np.testing.assert_allclose(got, np.asarray(dtw_band_cdist_ref(A, B, 2)),
                                rtol=1e-5, atol=1e-5)
 
@@ -410,7 +421,9 @@ def test_dispatch_pairwise_backends_agree(fresh_dispatch, n, L, window):
 
 
 @pytest.mark.parametrize("n,m,L,window", [(4, 6, 12, None), (9, 5, 16, 2),
-                                          (6, 6, 20, 40)])
+                                          (6, 6, 20, 40), (1, 256, 18, 2),
+                                          (35, 130, 64, 0),
+                                          (130, 3, 18, None)])
 def test_dispatch_cdist_backends_agree(fresh_dispatch, n, m, L, window):
     rng = np.random.default_rng(n * 13 + m)
     A = rng.standard_normal((n, L)).astype(np.float32)
@@ -508,6 +521,32 @@ def test_ivf_search_routes_through_dispatch(fresh_dispatch):
     np.testing.assert_allclose(np.asarray(got_d), np.asarray(want_d),
                                rtol=1e-5, atol=1e-4)
     assert (np.asarray(got_i) == np.asarray(want_i)).all()
+
+
+def test_coarse_assign_and_query_luts_backends_agree(fresh_dispatch):
+    """The all-pairs kernel's two serving callers: flush-time coarse
+    assignment gives the same list ids, and the query LUTs the same
+    tables, as the ``jax`` route."""
+    from repro.core.ivf import coarse_assign
+    from repro.core.pq import PQConfig, fit, query_lut_batch, segment
+    rng = np.random.default_rng(21)
+    X = np.cumsum(rng.standard_normal((40, 64)), 1).astype(np.float32)
+    cfg = PQConfig(n_sub=4, codebook_size=8, kmeans_iters=1, dba_iters=1)
+    with dispatch.use_backend("jax"):
+        cb = fit(jax.random.PRNGKey(4), X, cfg)
+        q_segs = segment(X[:5], cfg)
+        want_ids = np.asarray(coarse_assign(X, X[::5], 6))
+        want_lut = np.asarray(query_lut_batch(q_segs, cb, cfg.window(64),
+                                              measure=cfg.measure()))
+    jax.clear_caches()
+    dispatch.reset_stats()
+    with dispatch.use_backend("pallas_interpret"):
+        got_ids = np.asarray(coarse_assign(X, X[::5], 6))
+        got_lut = np.asarray(query_lut_batch(q_segs, cb, cfg.window(64),
+                                             measure=cfg.measure()))
+        assert _route_count("elastic_cdist") > 0
+    assert (got_ids == want_ids).all()
+    np.testing.assert_allclose(got_lut, want_lut, rtol=1e-5, atol=1e-5)
 
 
 def test_knn_exact_routes_through_dispatch(fresh_dispatch):

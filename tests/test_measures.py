@@ -136,7 +136,9 @@ def test_sweep_matches_oracle(measure, n, L, window):
 
 
 @pytest.mark.parametrize("measure", ALL_MEASURES)
-@pytest.mark.parametrize("n,m,L,window", [(4, 6, 12, None), (7, 5, 16, 3)])
+@pytest.mark.parametrize("n,m,L,window", [(4, 6, 12, None), (7, 5, 16, 3),
+                                          (35, 130, 18, 2), (1, 256, 64, 0),
+                                          (130, 3, 18, None)])
 def test_dispatch_cdist_backends_agree_per_measure(measure, n, m, L, window):
     """Acceptance: elastic_cdist agrees between jax and pallas_interpret
     for every registered measure."""

@@ -68,12 +68,22 @@ def test_dtw_band_compiles(shape, geometry):
     )
 
 
-@pytest.mark.parametrize("geometry", sorted(GEOMETRIES))
+# all-pairs launches as the served index makes them: (A rows, length,
+# window) against 256 lists or codewords
+CDIST_GEOMETRIES = {
+    **{name: (64, n, w) for name, (n, w) in GEOMETRIES.items()},
+    "serving_coarse": (64, L, 26),      # bucket 64, coarse band 0.1 * 256
+    "serving_lut": (64, S, S_WINDOW),   # one of the 16 query-LUT launches
+    "serving_flush": (4096, L, 26),     # a flush batch's coarse assignment
+}
+
+
+@pytest.mark.parametrize("geometry", sorted(CDIST_GEOMETRIES))
 def test_dtw_band_cdist_compiles(shape, geometry):
-    n, w = GEOMETRIES[geometry]
+    rows, n, w = CDIST_GEOMETRIES[geometry]
     _assert_kernel(
-        lambda a, b: dtw_band_cdist(a, b, w, interpret=False, lane=128),
-        shape(64, n),
+        lambda a, b: dtw_band_cdist(a, b, w, interpret=False),
+        shape(rows, n),
         shape(256, n),
     )
 
