@@ -366,7 +366,9 @@ def adc_lookup(codes: jnp.ndarray, qlut: jnp.ndarray, *,
                lut_dtype: str = "float32") -> jnp.ndarray:
     """Asymmetric ADC scan: ``codes (N, M)``, ``qlut (M, K)`` -> ``(N,)``.
 
-    Returns ``sqrt(sum_m qlut[m, codes[n, m]])`` per row.  ``lut_dtype``
+    Returns ``sqrt(sum_m qlut[m, codes[n, m]])`` per row.  A batch of
+    scans, each against its own table, is one launch: ``codes (B, N, M)``,
+    ``qlut (B, M, K)`` -> ``(B, N)`` (float32 tables only).  ``lut_dtype``
     mirrors :func:`adc_cdist`: ``"int8"`` / ``"bfloat16"`` run the
     quantized query-table kernel (ledgered ``adc_lookup_quant``).
 
@@ -384,6 +386,9 @@ def adc_lookup(codes: jnp.ndarray, qlut: jnp.ndarray, *,
     from ..kernels.pq_adc.ref import adc_lookup_quant_ref, adc_lookup_ref
     backend = get_backend()
     if lut_dtype != "float32":
+        if codes.ndim != 2:
+            raise ValueError("a quantized query table scans one (N, M) "
+                             "block of codes, not a batch")
         _count("adc_lookup_quant", backend)
         q, scale, zero = quantize_lut(qlut, lut_dtype)
         if backend == "jax":

@@ -29,12 +29,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .dispatch import elastic_cdist, two_level_coarse
+from .dispatch import adc_lookup, elastic_cdist, two_level_coarse
 from .kmeans import dba_kmeans
 from .lb import lb_lut
 from .measures import MeasureArg
-from .pq import (PQCodebook, PQConfig, _adc_gather, encode, fit,
-                 query_lut_batch, segment)
+from .pq import (PQCodebook, PQConfig, encode, fit, query_lut_batch,
+                 segment)
 
 __all__ = ["IVFPQIndex", "TwoLevelCoarse", "build_index", "build_lists",
            "build_two_level", "coarse_assign", "coarse_dists", "fine_rank",
@@ -219,17 +219,19 @@ def build_index(key: jax.Array, X: jnp.ndarray, cfg: PQConfig,
 def _candidates(list_start: jnp.ndarray, list_len: jnp.ndarray,
                 max_list: int, probe_lists: jnp.ndarray
                 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Static-shape candidate slots for ``n_probe`` lists.
+    """Static-shape candidate slots for each query's ``n_probe`` lists.
 
-    Returns (slots (n_probe*max_list,) int32 into codes/ids, valid mask).
+    ``probe_lists (Nq, P)`` -> (slots (Nq, P*max_list) int32 into
+    codes/ids, valid mask).
     """
     offs = jnp.arange(max_list)
-    start = list_start[probe_lists]                # (P,)
+    start = list_start[probe_lists]                # (Nq, P)
     length = list_len[probe_lists]
-    slots = start[:, None] + offs[None, :]         # (P, max_list)
-    valid = offs[None, :] < length[:, None]
+    slots = start[..., None] + offs                # (Nq, P, max_list)
+    valid = offs < length[..., None]
     slots = jnp.where(valid, slots, 0)
-    return slots.reshape(-1), valid.reshape(-1)
+    nq = probe_lists.shape[0]
+    return slots.reshape(nq, -1), valid.reshape(nq, -1)
 
 
 def fine_rank(codes: jnp.ndarray, ids: jnp.ndarray,
@@ -239,42 +241,47 @@ def fine_rank(codes: jnp.ndarray, ids: jnp.ndarray,
               lb_qlut: Optional[jnp.ndarray] = None,
               lb_budget: Optional[int] = None
               ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Rank one list-sorted shard against a single query.
+    """Rank one list-sorted shard against a batch of queries.
 
-    ``dc (n_lists,)`` coarse distances, ``qlut (M, K)`` asymmetric table;
-    ``live`` is an optional ``(N,)`` tombstone mask (False = deleted).
-    Returns ``(distances (topk,), ids (topk,))`` with ``inf`` / ``-1``
-    filling invalid slots, so shard results can be merged by a plain top-k.
+    ``dc (Nq, n_lists)`` coarse distances, ``qlut (Nq, M, K)`` asymmetric
+    tables; ``live`` is an optional ``(N,)`` tombstone mask (False =
+    deleted).  Returns ``(distances (Nq, topk), ids (Nq, topk))`` with
+    ``inf`` / ``-1`` filling invalid slots, so shard results can be merged
+    by a plain top-k.
 
-    ``lb_qlut (M, K)`` (see :func:`repro.core.lb.lb_lut`) enables the
+    ``lb_qlut (Nq, M, K)`` (see :func:`repro.core.lb.lb_lut`) enables the
     cascaded LB pre-filter: candidates are first ranked by their cheap
     lower-bound ADC sum and only the ``lb_budget`` most promising proceed
-    to the exact ADC gather.  The bound never exceeds the true asymmetric
+    to the exact ADC scan.  The bound never exceeds the true asymmetric
     distance, so with ``lb_budget == cap`` results are identical to the
-    unfiltered path; smaller budgets trade recall for gather work.
+    unfiltered path; smaller budgets trade recall for scan work.
+
+    Both sums are scored by :func:`repro.core.dispatch.adc_lookup`, one
+    launch for the batch's padded candidate blocks: on the chip the
+    one-hot MXU kernel, elsewhere its plain gather reference.
     """
-    _, probes = jax.lax.top_k(-dc, n_probe)
+    neg_dc, probes = jax.lax.top_k(-dc, n_probe)            # (Nq, P)
     slots, valid = _candidates(list_start, list_len, max_list, probes)
     # Hierarchical coarse stage (:func:`coarse_dists` with a two-level
     # quantizer) leaves unprobed lists at dc == +inf; if n_probe exceeds
     # the finite fan-out, top_k pads with such lists — their rows were
     # never coarse-ranked and must not become candidates.  Flat coarse
     # distances are always finite, so this is a no-op there.
-    valid = valid & jnp.repeat(jnp.isfinite(dc[probes]), max_list)
+    valid = valid & jnp.repeat(jnp.isfinite(neg_dc), max_list, axis=1)
     if live is not None:
         valid = valid & live[slots]
-    cand_codes = codes[slots]                               # (cap, M)
+    cand_codes = codes[slots]                               # (Nq, cap, M)
     if lb_qlut is not None and lb_budget is not None \
-            and lb_budget < slots.shape[0]:
-        lb_d = jnp.where(valid, _adc_gather(lb_qlut, cand_codes), jnp.inf)
+            and lb_budget < slots.shape[1]:
+        lb_d = jnp.where(valid, adc_lookup(cand_codes, lb_qlut), jnp.inf)
         _, keep = jax.lax.top_k(-lb_d, lb_budget)
-        slots = slots[keep]
-        valid = valid[keep]
-        cand_codes = cand_codes[keep]
-    d = jnp.where(valid, _adc_gather(qlut, cand_codes), jnp.inf)
+        slots = jnp.take_along_axis(slots, keep, axis=1)
+        valid = jnp.take_along_axis(valid, keep, axis=1)
+        cand_codes = jnp.take_along_axis(cand_codes, keep[..., None], axis=1)
+    d = jnp.where(valid, adc_lookup(cand_codes, qlut), jnp.inf)
     neg, best = jax.lax.top_k(-d, topk)
-    out_ids = jnp.where(jnp.isfinite(neg), ids[slots[best]], -1)
-    return -neg, out_ids
+    out_ids = ids[jnp.take_along_axis(slots, best, axis=1)]
+    return -neg, jnp.where(jnp.isfinite(neg), out_ids, -1)
 
 
 def _fine_stage(index: IVFPQIndex, dc: jnp.ndarray, qlut: jnp.ndarray,
@@ -336,18 +343,18 @@ def search_batch(index: IVFPQIndex, Q: jnp.ndarray, cfg: PQConfig, *,
                  n_probe_top: Optional[int] = None):
     """Batched search over queries ``Q (Nq, D)``.
 
-    The coarse DTW stage and the asymmetric query tables are computed for
-    the whole batch in two dispatch-layer launches (Pallas kernels on TPU);
-    only the cheap probe/gather/top-k tail is vmapped.
+    The coarse DTW stage, the asymmetric query tables and the fine ADC
+    scan are each computed for the whole batch in dispatch-layer launches
+    (Pallas kernels on TPU).
 
     ``coarse_window`` defaults to ``index.coarse_window`` — the band the
     inverted lists were assigned with at build time — so probe ranking
     always matches the list-assignment metric unless explicitly overridden.
     ``lb_budget`` enables the cascaded LB pre-filter in the fine stage
     (see :func:`fine_rank`): candidates beyond the budget are discarded on
-    their envelope lower bound before the exact ADC gather.  The budget is
+    their envelope lower bound before the exact ADC scan.  The budget is
     capability-gated: for measures without a sound Keogh cascade it is
-    ignored (exact full gather) instead of pruning unsoundly.
+    ignored (exact full scan) instead of pruning unsoundly.
 
     ``two_level`` + ``n_probe_top`` switch the coarse stage to the
     hierarchical quantizer (:func:`build_two_level`): probe ranking is
@@ -369,14 +376,12 @@ def search_batch(index: IVFPQIndex, Q: jnp.ndarray, cfg: PQConfig, *,
                             not cfg.is_elastic, spec)       # (Nq, M, K)
     if lb_budget is not None and spec is not None and not spec.has_keogh_lb:
         # The envelope bound table is only a lower bound for measures with
-        # a sound Keogh cascade; fall back to the exact full gather rather
+        # a sound Keogh cascade; fall back to the exact full scan rather
         # than an unsound prune.
         lb_budget = None
     if lb_budget is not None and lb_budget < n_probe * index.max_list:
         lb_luts = lb_lut(q_segs, index.cb.centroids, index.cb.env_upper,
                          index.cb.env_lower)                # (Nq, M, K)
-        fn = lambda dcr, ql, lbl: _fine_stage(index, dcr, ql, n_probe,
-                                              topk, lbl, lb_budget)
-        return jax.vmap(fn)(dc, qluts, lb_luts)
-    fn = lambda dcr, ql: _fine_stage(index, dcr, ql, n_probe, topk)
-    return jax.vmap(fn)(dc, qluts)
+        return _fine_stage(index, dc, qluts, n_probe, topk, lb_luts,
+                           lb_budget)
+    return _fine_stage(index, dc, qluts, n_probe, topk)

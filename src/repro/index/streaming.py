@@ -113,15 +113,15 @@ class IndexConfig:
 @functools.partial(jax.jit, static_argnames=("max_list", "n_probe", "k"))
 def _rank_segment(codes, ids, live, list_start, list_len, dc, qluts, *,
                   max_list: int, n_probe: int, k: int):
-    """vmap'd fine stage over one sealed segment -> ``(Nq, k)`` d, ids.
+    """Fine stage of a query batch over one sealed segment -> ``(Nq, k)``
+    d, ids.
 
     Jitted per *shape*, not per segment: every flush-born segment (same
     padded rows, same ``max_list`` = hot capacity) reuses one compiled
     fine stage regardless of how many segments exist."""
-    fn = lambda dcr, ql: fine_rank(codes, ids, list_start, list_len,
-                                   max_list, dcr, ql, n_probe, k, live=live)
     with jax.named_scope("index.search.fine"):
-        return jax.vmap(fn)(dc, qluts)
+        return fine_rank(codes, ids, list_start, list_len, max_list, dc,
+                         qluts, n_probe, k, live=live)
 
 
 @functools.partial(jax.jit, static_argnames=("window", "k", "euclidean",

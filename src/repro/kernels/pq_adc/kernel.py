@@ -140,18 +140,21 @@ def make_adc_sym_call(nA: int, nB: int, n_sub: int, K: int,
     )
 
 
-def make_adc_lookup_call(n: int, n_sub: int, K: int, block: int,
+def make_adc_lookup_call(b: int, n: int, n_sub: int, K: int, block: int,
                          interpret: bool):
+    """``b`` scans, each of ``n`` code rows against its own table: codes
+    ``(b, n, M)`` and tables ``(b, M, K)`` -> ``(b, n, 1)``.  The scans
+    are the grid's leading axis."""
     kernel = functools.partial(adc_lookup_kernel, n_sub=n_sub, K=K)
     return pl.pallas_call(
         kernel,
-        grid=(n // block,),
+        grid=(b, n // block),
         in_specs=[
-            pl.BlockSpec((block, n_sub), lambda i: (i, 0)),
-            pl.BlockSpec((n_sub, K), lambda i: (0, 0)),
+            pl.BlockSpec((None, block, n_sub), lambda q, i: (q, i, 0)),
+            pl.BlockSpec((None, n_sub, K), lambda q, i: (q, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((block, 1), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n, 1), jnp.float32),
+        out_specs=pl.BlockSpec((None, block, 1), lambda q, i: (q, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, n, 1), jnp.float32),
         interpret=interpret,
     )
 

@@ -92,16 +92,22 @@ def adc_sym_cdist(codes_a: jnp.ndarray, codes_b: jnp.ndarray,
 def adc_lookup(codes: jnp.ndarray, qlut: jnp.ndarray,
                block: Optional[int] = None,
                interpret: Optional[bool] = None) -> jnp.ndarray:
-    """Asymmetric scan: ``codes (N, M)``, ``qlut (M, K)`` -> ``(N,)``."""
+    """Asymmetric scan: ``codes (N, M)``, ``qlut (M, K)`` -> ``(N,)``, or
+    a batch of scans, each against its own table: ``codes (B, N, M)``,
+    ``qlut (B, M, K)`` -> ``(B, N)``."""
     if interpret is None:
         interpret = default_interpret()
-    n, M = codes.shape
-    K = qlut.shape[-1]
+    batched = codes.ndim == 3
+    c = codes if batched else codes[None]
+    q = qlut if batched else qlut[None]
+    b, n, M = c.shape
+    K = q.shape[-1]
     block = _tuned("adc_lookup", "block", block, K, interpret, 256)
     block = min(block, max(8, n))
-    c = pad_to(codes.astype(jnp.int32), block, axis=0, value=0)
-    call = make_adc_lookup_call(c.shape[0], M, K, block, interpret)
-    return call(c, qlut.astype(jnp.float32))[:n, 0]
+    c = pad_to(c.astype(jnp.int32), block, axis=1, value=0)
+    call = make_adc_lookup_call(b, c.shape[1], M, K, block, interpret)
+    d = call(c, q.astype(jnp.float32))[:, :n, 0]
+    return d if batched else d[0]
 
 
 @functools.partial(jax.jit, static_argnames=("block_a", "block_b", "interpret"))

@@ -35,6 +35,8 @@ def adc_sym_cdist_ref(codes_a: jnp.ndarray, codes_b: jnp.ndarray,
 
 @jax.jit
 def adc_lookup_ref(codes: jnp.ndarray, qlut: jnp.ndarray) -> jnp.ndarray:
+    if codes.ndim == 3:  # a batch of scans, each against its own table
+        return jax.vmap(adc_lookup_ref)(codes, qlut)
     m_idx = jnp.arange(qlut.shape[0])
     d2 = jnp.sum(qlut[m_idx[None, :], codes.astype(jnp.int32)], axis=-1)
     return jnp.sqrt(jnp.maximum(d2, 0.0))

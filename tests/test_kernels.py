@@ -172,6 +172,25 @@ def test_adc_lookup_matches_ref(n, M, K):
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
 
 
+@pytest.mark.parametrize("b,n,M,K", [(1, 5, 3, 8), (3, 100, 7, 256), (4, 257, 16, 256)])
+def test_adc_lookup_batched_matches_ref(b, n, M, K):
+    """A batch of scans, each against its own table, in one launch: the
+    batch is the grid's leading axis, every scan equals its own launch."""
+    rng = np.random.default_rng(b * n)
+    qlut = np.abs(rng.standard_normal((b, M, K))).astype(np.float32)
+    codes = rng.integers(0, K, (b, n, M)).astype(np.int32)
+    got = np.asarray(adc_lookup(codes, qlut, block=32, interpret=True))
+    want = np.asarray(adc_lookup_ref(codes, qlut))
+    assert got.shape == want.shape == (b, n)
+    np.testing.assert_allclose(got, want, rtol=1e-6)
+    for q in range(b):
+        np.testing.assert_array_equal(
+            got[q], np.asarray(adc_lookup(codes[q], qlut[q], block=32,
+                                          interpret=True)))
+        np.testing.assert_array_equal(want[q], np.asarray(
+            adc_lookup_ref(codes[q], qlut[q])))
+
+
 def test_adc_sym_consistent_with_core_pq():
     """Kernel output must equal the core library's symmetric distance."""
     from repro.core.pq import cdist_sym

@@ -283,10 +283,8 @@ class TestCoalescer:
         np.testing.assert_array_equal(np.asarray(r.dist),
                                       np.asarray(d_direct))
 
-    def test_warm_buckets_trigger_no_new_compilations(self, data, booted):
-        """After one warmup pass over the traffic's buckets, steady-state
-        mixed-size traffic adds zero trace-time dispatch counts: the
-        finite bucket family really does pin the compiled executables."""
+    @staticmethod
+    def _assert_warm_buckets_pin_compiles(data, booted):
         X, Q = data
         idx = _fresh(booted)
         idx.insert(X[:20])
@@ -316,6 +314,26 @@ class TestCoalescer:
             # any re-trace of a jitted stage would bump its counter past
             # the eager-only expectation
             assert dict(dispatch.totals) == want
+
+    def test_warm_buckets_trigger_no_new_compilations(self, data, booted):
+        """After one warmup pass over the traffic's buckets, steady-state
+        mixed-size traffic adds zero trace-time dispatch counts: the
+        finite bucket family really does pin the compiled executables."""
+        self._assert_warm_buckets_pin_compiles(data, booted)
+
+    def test_warm_buckets_pin_compiles_with_adc_kernel(self, data, booted):
+        """The same steady state with every dispatched op, the fine stage's
+        one-hot ADC kernel among them, on the Pallas kernel bodies."""
+        route = ("adc_lookup", "pallas_interpret")
+        jax.clear_caches()   # routes are chosen when a stage is traced
+        try:
+            before = dispatch.totals.get(route, 0)
+            with dispatch.use_backend("pallas_interpret"):
+                self._assert_warm_buckets_pin_compiles(data, booted)
+            # one trace of the fine stage per warmed bucket, none after
+            assert dispatch.totals.get(route, 0) == before + 3
+        finally:
+            jax.clear_caches()
 
     def test_graceful_stop_answers_queued_requests(self, data, booted):
         X, Q = data

@@ -110,6 +110,47 @@ def test_adc_lookup_compiles(shape):
     )
 
 
+# the fine stage as the served index runs it, 8 of 256 lists probed, top
+# 10: (bucket, segment rows, max_list) for a compacted 2^17-row segment
+# padded to its longest list and a flush-born segment padded to the hot
+# buffer's capacity
+RANK_GEOMETRIES = {
+    f"{kind}_q{q}": (q, rows, width)
+    for q in (1, 64)
+    for kind, rows, width in (("compacted", 131072, 2048), ("flush", 4096, 4096))
+}
+
+
+@pytest.mark.parametrize("geometry", sorted(RANK_GEOMETRIES))
+def test_rank_segment_compiles(shape, geometry, monkeypatch):
+    """``_rank_segment`` scores the batch's padded candidate blocks in one
+    ADC launch whose grid leads with the query.  The ``pallas`` route runs
+    the kernel compiled only where JAX's default backend is a TPU; here
+    the test tells it so."""
+    from repro.core import dispatch
+    from repro.index.streaming import _rank_segment
+
+    monkeypatch.setattr("repro.kernels.pq_adc.ops.default_interpret", lambda: False)
+    nq, rows, width = RANK_GEOMETRIES[geometry]
+    jax.clear_caches()  # the route is chosen when the stage is traced
+    try:
+        with dispatch.use_backend("pallas"):
+            _assert_kernel(
+                lambda codes, ids, live, start, length, dc, qluts: _rank_segment(
+                    codes, ids, live, start, length, dc, qluts,
+                    max_list=width, n_probe=8, k=10),
+                shape(rows, M, dtype=jnp.int32),
+                shape(rows, dtype=jnp.int32),
+                shape(rows, dtype=jnp.bool_),
+                shape(256, dtype=jnp.int32),
+                shape(256, dtype=jnp.int32),
+                shape(nq, 256),
+                shape(nq, M, K),
+            )
+    finally:
+        jax.clear_caches()
+
+
 def test_adc_sym_cdist_compiles(shape):
     codes = shape(512, M, dtype=jnp.int32)
     _assert_kernel(
